@@ -307,7 +307,14 @@ class SymExecWrapper:
                     ),
                 )
                 outcome = explorer.run()
-            except Exception as why:  # the host walk must never be blocked
+            except Exception as why:
+                if mode == "always":
+                    from mythril_tpu.exceptions import DevicePrepassError
+
+                    raise DevicePrepassError(
+                        f"device prepass failed: {why!r}"
+                    ) from why
+                # auto: the host walk must never be blocked
                 log.debug("device prepass failed: %s", why)
                 return None
 

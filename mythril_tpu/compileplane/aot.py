@@ -27,7 +27,6 @@ import pickle
 
 #: AOT_UNSUPPORTED reasons (stable label vocabulary)
 REASON_DISABLED = "disabled"  # --no-aot / MYTHRIL_NO_AOT
-REASON_NO_SUPPORT = "no-serialize-support"  # import failed
 REASON_SERIALIZE = "serialize-failed"
 REASON_DESERIALIZE = "deserialize-failed"
 REASON_LOWER = "lower-failed"  # .lower()/.compile() itself
@@ -55,26 +54,27 @@ def aot_enabled() -> bool:
     return bool(getattr(args, "aot", True))
 
 
-def _serialize_module():
-    try:
-        from jax.experimental import serialize_executable
-    except Exception as why:  # pragma: no cover - backend-dependent
-        raise AotUnsupported(REASON_NO_SUPPORT, str(why))
-    return serialize_executable
-
-
 def serialize_compiled(compiled) -> bytes:
     """Compiled (from `jit.lower().compile()`) -> portable bytes:
     pickle of the (payload, in_tree, out_tree) triple
-    serialize_executable.serialize returns."""
-    se = _serialize_module()
+    serialize_executable.serialize returns, plus the ids of the devices
+    the executable runs on. `deserialize_and_load` otherwise loads onto
+    EVERY device of the backend, and a one-device kernel then expects
+    one shard per device on a multi-device host."""
+    from jax.experimental import serialize_executable as se
+
     try:
-        triple = se.serialize(compiled)
+        payload, in_tree, out_tree = se.serialize(compiled)
+        device_ids = [
+            d.id for d in compiled.runtime_executable().local_devices()
+        ]
         buf = io.BytesIO()
-        pickle.dump(triple, buf, protocol=pickle.HIGHEST_PROTOCOL)
+        pickle.dump(
+            (payload, in_tree, out_tree, device_ids),
+            buf,
+            protocol=pickle.HIGHEST_PROTOCOL,
+        )
         return buf.getvalue()
-    except AotUnsupported:
-        raise
     except Exception as why:
         raise AotUnsupported(REASON_SERIALIZE, str(why))
 
@@ -83,11 +83,17 @@ def load_serialized(blob: bytes):
     """Portable bytes -> a callable Compiled, invoked with the dynamic
     arguments only. Artifacts come from the operator-owned cache/pack
     directories (same trust domain as the code being analyzed)."""
-    se = _serialize_module()
+    import jax
+    from jax.experimental import serialize_executable as se
+
     try:
-        payload, in_tree, out_tree = pickle.loads(blob)
-        return se.deserialize_and_load(payload, in_tree, out_tree)
-    except AotUnsupported:
-        raise
+        payload, in_tree, out_tree, device_ids = pickle.loads(blob)
+        by_id = {d.id: d for d in jax.local_devices()}
+        return se.deserialize_and_load(
+            payload,
+            in_tree,
+            out_tree,
+            execution_devices=[by_id[i] for i in device_ids],
+        )
     except Exception as why:
         raise AotUnsupported(REASON_DESERIALIZE, str(why))

@@ -35,16 +35,11 @@ def backend_fingerprint() -> Dict:
 
         from mythril_tpu.ops import u256
 
-        try:
-            devices = jax.devices()
-            device_kind = devices[0].device_kind if devices else "none"
-        except Exception:
-            device_kind = "none"
         _FP = {
-            "jax": getattr(jax, "__version__", "unknown"),
-            "jaxlib": getattr(jaxlib, "__version__", "unknown"),
+            "jax": jax.__version__,
+            "jaxlib": jaxlib.__version__,
             "backend": jax.default_backend(),
-            "device_kind": device_kind,
+            "device_kind": jax.devices()[0].device_kind,
             "xla_flags": os.environ.get("XLA_FLAGS", ""),
             "limbs": int(u256.LIMBS),
         }

@@ -268,17 +268,15 @@ def bake_service_pack(
     prev_cc_dir = jax.config.jax_compilation_cache_dir
     jax.config.update("jax_compilation_cache_dir", None)
     # clearing the dir is not enough on its own: jax latches the
-    # cache-used decision at the first compile of the process
-    # (is_cache_used memoizes), so a bake after any cached compile
-    # would still read stubs out of the persistent cache. Reset the
-    # latch so the dir=None takes effect, and reset again afterwards
-    # so post-bake compiles re-latch against the restored dir.
-    try:
-        from jax._src import compilation_cache as _jax_cc
-    except Exception:  # pragma: no cover - internal layout drift
-        _jax_cc = None
-    if _jax_cc is not None:
-        _jax_cc.reset_cache()
+    # cache-used decision at the first compile of the process, so reset
+    # the latch so the dir=None takes effect (and again afterwards so
+    # post-bake compiles re-latch against the restored dir). The
+    # in-memory compile memo must go too: it would hand the bake an
+    # executable an earlier compile loaded from the persistent cache.
+    from jax.experimental.compilation_cache import compilation_cache
+
+    compilation_cache.reset_cache()
+    jax.clear_caches()
     try:
         for phases in buckets:
             for donate in donate_variants:
@@ -332,8 +330,7 @@ def bake_service_pack(
         return manifest
     finally:
         jax.config.update("jax_compilation_cache_dir", prev_cc_dir)
-        if _jax_cc is not None:
-            _jax_cc.reset_cache()
+        compilation_cache.reset_cache()
         install_plane(previous)
 
 

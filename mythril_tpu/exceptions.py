@@ -58,6 +58,11 @@ class WatchdogTimeout(MythrilBaseException):
     treated as lost and rebuilt."""
 
 
+class DevicePrepassError(MythrilBaseException):
+    """The device prepass failed under --device-prepass always: the run
+    must fail rather than go on host-only (auto degrades instead)."""
+
+
 class DeviceDispatchError(MythrilBaseException):
     """A device dispatch kept failing after retries and the reduced-
     capacity fallback — the caller degrades the work to the host."""

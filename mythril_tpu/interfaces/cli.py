@@ -29,6 +29,7 @@ from mythril_tpu.exceptions import (
     CriticalError,
     DeadlineExpiredError,
     DetectorNotFoundError,
+    DevicePrepassError,
 )
 from mythril_tpu.laser.ethereum.transaction.symbolic import ACTORS
 from mythril_tpu.mythril import (
@@ -116,9 +117,13 @@ CREATION_INPUT_FLAGS = [
     (
         ("-f", "--codefile"),
         dict(
-            help="file containing hex-encoded bytecode string",
+            help=(
+                "file containing hex-encoded bytecode string; several "
+                "files load one contract each, named after the file"
+            ),
             metavar="BYTECODEFILE",
             type=argparse.FileType("r"),
+            nargs="+",
         ),
     ),
 ]
@@ -1890,7 +1895,9 @@ def validate_args(args: Namespace):
             )
         logging.getLogger("mythril_tpu").setLevel(chosen)
 
-    if args.command in DISASSEMBLE_LIST and len(args.solidity_files) > 1:
+    if args.command in DISASSEMBLE_LIST and (
+        len(args.solidity_files) > 1 or len(args.codefile or ()) > 1
+    ):
         exit_with_error(
             "text", "Only a single arg is supported for using disassemble"
         )
@@ -1929,6 +1936,11 @@ def leveldb_search(config: MythrilConfig, args: Namespace):
     sys.exit()
 
 
+def _read_codefile(codefile) -> str:
+    with codefile:
+        return "".join(line.strip() for line in codefile if line.strip())
+
+
 def load_code(disassembler: MythrilDisassembler, args: Namespace):
     """Load the analysis target from whichever input flag was given."""
     opt = args.__dict__.get
@@ -1939,12 +1951,15 @@ def load_code(disassembler: MythrilDisassembler, args: Namespace):
             blob[2:] if blob.startswith("0x") else blob, args.bin_runtime
         )
     elif opt("codefile"):
-        blob = "".join(
-            line.strip() for line in args.codefile if line.strip()
-        )
-        address, _ = disassembler.load_from_bytecode(
-            blob[2:] if blob.startswith("0x") else blob, args.bin_runtime
-        )
+        for codefile in args.codefile:
+            blob = _read_codefile(codefile)
+            address, contract = disassembler.load_from_bytecode(
+                blob[2:] if blob.startswith("0x") else blob, args.bin_runtime
+            )
+            if len(args.codefile) > 1:
+                # one report over several contracts: tell them apart
+                name = os.path.basename(codefile.name)
+                contract.name = name.rsplit(".", 1)[0]
     elif opt("address"):
         address, _ = disassembler.load_from_address(args.address)
     elif opt("solidity_files"):
@@ -2253,6 +2268,10 @@ def _run_analyze(disassembler, address, args):
         exit_with_error(
             args.outform, "Analysis deadline expired: " + format(e), exit_code=1
         )
+    except DevicePrepassError as e:
+        # --device-prepass always: a run without the device is a
+        # failed run, and the exit code says so
+        exit_with_error(args.outform, format(e), exit_code=1)
     except CriticalError as e:
         exit_with_error(args.outform, "Analysis error encountered: " + format(e))
     finally:
@@ -2877,7 +2896,10 @@ def _cmd_submit(args: Namespace) -> None:
     elif args.code:
         blob = args.code
     elif args.codefile:
-        blob = "".join(line.strip() for line in args.codefile if line.strip())
+        if len(args.codefile) > 1:
+            log.error("submit takes one bytecode file")
+            sys.exit(1)
+        blob = _read_codefile(args.codefile[0])
     else:
         log.error(
             "No input bytecode. Provide EVM code via -c BYTECODE, "
@@ -3129,8 +3151,16 @@ def parse_args_and_execute(parser: ArgumentParser, args: Namespace) -> None:
 
 def main() -> None:
     """CLI entry point."""
+    from mythril_tpu.support.accel import ensure_compile_cache
+
+    ensure_compile_cache()
     parser = build_parser()
-    parse_args_and_execute(parser=parser, args=parser.parse_args())
+    try:
+        parse_args_and_execute(parser=parser, args=parser.parse_args())
+    finally:
+        corpus = sys.modules.get("mythril_tpu.analysis.corpus")
+        if corpus is not None:
+            corpus.join_stopped_prepasses()
 
 
 if __name__ == "__main__":

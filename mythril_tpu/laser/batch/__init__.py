@@ -1,35 +1,11 @@
 """Batched concrete EVM interpreter (device-side)."""
 
-import os
-
-
-def ensure_compile_cache() -> None:
-    """Point JAX at a persistent compilation cache so the step/sym_step
-    kernels compile once per shape class per machine, not once per
-    process. Code capacities are bucketed to powers of two
-    (seeds.code_cap_bucket) precisely so corpus runs hit this cache."""
-    import jax
-
-    if jax.config.jax_compilation_cache_dir:
-        return  # caller (or conftest) already configured one
-    cache_dir = os.environ.get(
-        "MYTHRIL_TPU_XLA_CACHE",
-        os.path.join(os.path.expanduser("~"), ".mythril", "xla_cache"),
-    )
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:
-        pass  # cache is an optimization, never a requirement
-
-
-from mythril_tpu.laser.batch.state import (  # noqa: F401,E402
+from mythril_tpu.laser.batch.state import (  # noqa: F401
     CodeTable,
     StateBatch,
     Status,
     make_batch,
     make_code_table,
 )
-from mythril_tpu.laser.batch.step import step  # noqa: F401,E402
-from mythril_tpu.laser.batch.run import run  # noqa: F401,E402
+from mythril_tpu.laser.batch.step import step  # noqa: F401
+from mythril_tpu.laser.batch.run import run  # noqa: F401

@@ -273,7 +273,7 @@ def _unhandled_table(phases: PhaseSet) -> np.ndarray:
 # Stack-peek implementation: "gather" (take_along_axis) or "einsum"
 # (one-hot contraction). The limbs-major probe measured the contraction
 # at 2/3 the kernel-segment count of the gather, and the full step
-# kernel at +18% throughput on the v5e link (439k -> 520k
+# kernel at +18% throughput on the pre-PR-21 remote v5e link (historical) (439k -> 520k
 # transitions/s); segment count is the latency unit on
 # dispatch-floor-bound links (docs/roadmap.md). On CPU the one-hot
 # multiply is pure overhead, so the default is per-backend; the
@@ -293,7 +293,7 @@ def _gate(pred, fn, operands):
     """Whole-batch phase gate: `lax.cond` skips the phase when no lane
     triggers it. (Probed once: inlining every phase unconditionally —
     the handlers are mask-correct either way — crashed the TPU worker
-    outright on the v5e link, so the conditionals stay.)"""
+    outright on the pre-PR-21 remote v5e link (historical), so the conditionals stay.)"""
     return lax.cond(pred, fn, lambda x: x, operands)
 
 

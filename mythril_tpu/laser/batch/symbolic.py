@@ -68,7 +68,7 @@ from mythril_tpu.ops import u256
 from mythril_tpu.support.opcodes import OPCODES
 
 W = u256.LIMBS
-OPAQUE = jnp.int32(-1)
+OPAQUE = np.int32(-1)  # numpy: importing must not initialize a backend
 
 #: arena rows per batch (shared by all lanes of a wave)
 ARENA_CAP = 32768

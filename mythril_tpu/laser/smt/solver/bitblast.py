@@ -401,13 +401,6 @@ class PyBlaster:
 # native-backed implementation
 # ---------------------------------------------------------------------------
 
-_BLAST_LIB_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))),
-    "native",
-    "libmythril_native.so",
-)
-
 _blib = None
 _blib_failed = False
 
@@ -417,7 +410,9 @@ def _load_blast_lib():
     if _blib is not None or _blib_failed:
         return _blib
     try:
-        lib = ctypes.CDLL(_BLAST_LIB_PATH)
+        from mythril_tpu.native import library_path
+
+        lib = ctypes.CDLL(library_path())
         P = ctypes.POINTER(ctypes.c_int32)
         lib.bl_new.restype = ctypes.c_void_p
         lib.bl_free.argtypes = [ctypes.c_void_p]
@@ -507,7 +502,7 @@ class NativeBlaster:
     def __init__(self):
         lib = _load_blast_lib()
         if lib is None:
-            raise OSError(f"native blast library not loadable: {_BLAST_LIB_PATH}")
+            raise OSError("native blast library not loadable")
         self._lib = lib
         self._h = lib.bl_new()
         self.flat = NativeFlat(self)

@@ -9,16 +9,8 @@ Python/JAX, the native side does pure SAT.
 from __future__ import annotations
 
 import ctypes
-import os
 import time
 from typing import Dict, List, Optional
-
-_LIB_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))),
-    "native",
-    "libmythril_native.so",
-)
 
 _lib = None
 
@@ -26,7 +18,9 @@ _lib = None
 def _load():
     global _lib
     if _lib is None:
-        lib = ctypes.CDLL(_LIB_PATH)
+        from mythril_tpu.native import library_path
+
+        lib = ctypes.CDLL(library_path())
         lib.cdcl_new.restype = ctypes.c_void_p
         lib.cdcl_delete.argtypes = [ctypes.c_void_p]
         lib.cdcl_new_var.argtypes = [ctypes.c_void_p]

@@ -230,6 +230,10 @@ def compile_program(
     return compile_program_ex(lowered, max_limbs)[0]
 
 
+def _power_of_two(t: Term) -> bool:
+    return t.op == "const" and t.args[0] > 0 and not t.args[0] & (t.args[0] - 1)
+
+
 def compile_program_ex(
     lowered: List[Term], max_limbs: int = DEFAULT_MAX_LIMBS
 ) -> Tuple[Optional[Program], Optional[str]]:
@@ -336,6 +340,13 @@ def compile_program_ex(
             args[i, 1] = index[sh._id]
             imms[i, 0] = intern_const(1 << (w - 1))
             imms[i, 1] = intern_const((1 << w) - 1)
+        elif op == "udiv" and _power_of_two(t.args[1]):
+            # a / 2^k is a >> k: the selector shift every dispatcher
+            # query carries, without the 256-step long division
+            a, d = t.args
+            opcodes[i] = OP_INDEX["extract"]
+            args[i, 0] = index[a._id]
+            imms[i, 0] = d.args[0].bit_length() - 1
         elif op == "ite":
             c, a, b = t.args
             opcodes[i] = OP_INDEX["ite"]
@@ -514,11 +525,11 @@ def _get_search_fn(K: int, L: int, steps: int):
     def popcount_bits(x):
         return jax.lax.population_count(x).sum(axis=-1).astype(jnp.int32)
 
-    def eval_program(opcodes, args, imms, widths, pool, X):
+    def eval_program(opcodes, args, imms, widths, pool, X, n_nodes):
         N = opcodes.shape[0]
         values = jnp.zeros((N, K, L), dtype=jnp.uint32)
 
-        def body(values, i):
+        def body(i, values):
             op = opcodes[i]
             a = values[args[i, 0]]
             b = values[args[i, 1]]
@@ -606,17 +617,20 @@ def _get_search_fn(K: int, L: int, steps: int):
                 w == 1, mask.at[1].set(jnp.uint32(LIMB_MASK)), mask
             )
             out = out & jnp.broadcast_to(mask, (K, L))
-            return values.at[i].set(out), None
+            return values.at[i].set(out)
 
-        values, _ = jax.lax.scan(body, values, jnp.arange(N, dtype=jnp.int32))
-        return values
+        # the real nodes only: the bucket's padding nodes feed no root
+        return jax.lax.fori_loop(0, n_nodes, body, values)
 
     def to_bool_word(x):
         """Truthiness of a plain word value (non-bool nodes)."""
         return jnp.logical_not(u256.is_zero(x))
 
     def score(opcodes, args, imms, widths, pool, roots, roots_mask, X):
-        values = eval_program(opcodes, args, imms, widths, pool, X)
+        # nodes are in post-order over the roots: the last real node is
+        # the highest root
+        n_nodes = jnp.max(jnp.where(roots_mask, roots, 0)) + 1
+        values = eval_program(opcodes, args, imms, widths, pool, X, n_nodes)
         rv = values[roots]  # [R, K, L]
         hard = (rv[..., 0] != 0) | ~roots_mask[:, None]
         soft = jnp.where(
@@ -815,7 +829,7 @@ def _get_search_fn(K: int, L: int, steps: int):
 
     fn = _jax.jit(search)
     fn.score = _jax.jit(score)
-    fn.raw = search  # unjitted form, for vmapping into batched dispatch
+    fn.raw = search  # unjitted form, for the batched dispatch
     _eval_cache[key] = fn
     return fn
 
@@ -876,49 +890,20 @@ def _decode_assignment(
     return assignment
 
 
-def _sls_batch(
-    live: List[Tuple[int, Program]],
-    candidates: int = 64,
-    steps: int = 512,
-    seed: int = 7,
-    n_devices: int = 1,
-    devices=None,
-) -> Dict[int, Dict[str, int]]:
-    """ONE batched diversified-SLS dispatch over many compiled
-    programs: every stacked axis pads to the max bucket over the
-    batch, the programs stack on a leading axis, and one vmapped
-    search runs K heterogeneous candidates for all of them
-    concurrently — the whole batch costs one dispatch chain, so its
-    cost does not grow with query count. With n_devices > 1 the query
-    axis shards over the devices (pmap over Q-chunks); an explicit
-    `devices` list pins the shards to a scheduler group's own chips.
-    Returns {live index: raw assignment} for solved entries (decoded,
-    NOT yet validated)."""
-    out: Dict[int, Dict[str, int]] = {}
-    if not live:
-        return out
-    if len(live) == 1:
-        i, prog = live[0]
-        asn = device_check(
-            prog.source, candidates, steps, seed,
-            n_devices=n_devices, prog=prog,
-        )
-        if asn is not None:
-            out[i] = asn
-        return out
-
-    import jax
+def _stacked_args(live: List[Tuple[int, Program]], Q: int):
+    """The batched search kernel's arguments for `live` programs, Q
+    rows -> (args, L): every other stacked axis padded to one shared
+    bucket."""
     import jax.numpy as jnp
 
     # One shared shape bucket: every stacked axis padded to the max
-    # bucket over the batch, so the vmapped kernel compiles once per
+    # bucket over the batch, so the batched kernel compiles once per
     # (Q, N, C, R, V, L, K, steps) class rather than once per query.
     N = max(p.opcodes.shape[0] for _, p in live)
     C = max(p.const_pool.shape[0] for _, p in live)
     R = max(p.roots.shape[0] for _, p in live)
     V = _bucket(max(len(p.var_slots) for _, p in live), 4)
     L = max(p.limbs for _, p in live)
-    Q = _bucket(len(live), 4)
 
     def stack(getter, shape, dtype, fill=0):
         arr = np.full((Q,) + shape, fill, dtype=dtype)
@@ -972,42 +957,120 @@ def _sls_batch(
         ),
     )
 
-    fn = _get_search_fn(candidates, L, steps)
-    seeds = jnp.arange(seed, seed + Q, dtype=jnp.int32)
-    # largest power-of-two device count that divides Q (Q is bucketed
-    # to a power of two, so any pow2 <= min(n_devices, Q) divides it),
-    # clamped to the devices that actually exist
+    return args, L
+
+
+def _search_rows(search):
+    """The batched search over stacked queries: one query after another
+    (lax.map), each row's search skipped when its `live` flag is off.
+
+    Not vmap: a vmapped query's opcodes are batched, so the
+    interpreter's `lax.switch` evaluates EVERY branch at every node and
+    selects — including the 256-step long division of udiv/urem at all
+    of a program's nodes, on every one of its up to `steps` evaluations.
+    On one v5e chip that made a 12-query flip-funnel dispatch run for
+    minutes (PR 21); mapped, the opcode stays a scalar, the switch runs
+    only the taken branch, and each query's while-loop ends when it
+    solves, not when the slowest query of the batch does."""
+    import jax
+    import jax.numpy as jnp
+
+    def one(row):
+        *args, seed, live = row
+        V, L = args[7].shape[0], args[4].shape[-1]
+        return jax.lax.cond(
+            live,
+            lambda: search(*args, seed),
+            lambda: (jnp.bool_(False), jnp.zeros((V, L), jnp.uint32)),
+        )
+
+    return lambda *stacked: jax.lax.map(one, stacked)
+
+
+#: queries per batched search dispatch: every dispatch stacks exactly
+#: this many rows (one compiled shape), and the owner's stop is checked
+#: between dispatches
+SEARCH_ROWS = 16
+
+
+def _sls_batch(
+    live: List[Tuple[int, Program]],
+    candidates: int = 64,
+    steps: int = 512,
+    seed: int = 7,
+    n_devices: int = 1,
+    devices=None,
+    should_stop=None,
+) -> Dict[int, Dict[str, int]]:
+    """Batched diversified SLS over many compiled programs: they stack
+    on a leading axis, SEARCH_ROWS per dispatch, every other stacked
+    axis padded to the max bucket over the batch, and each dispatch
+    searches its rows in turn (`_search_rows`), K heterogeneous
+    candidates each. With n_devices > 1 the rows shard over the
+    devices (pmap over row chunks); an explicit `devices` list pins
+    the shards to a scheduler group's own chips. `should_stop()` is
+    checked before every dispatch; the rows not yet dispatched stay
+    unsolved. Returns {live index: raw assignment} for solved entries
+    (decoded, NOT yet validated)."""
+    out: Dict[int, Dict[str, int]] = {}
+    if not live:
+        return out
+    if len(live) == 1:
+        i, prog = live[0]
+        asn = device_check(
+            prog.source, candidates, steps, seed,
+            n_devices=n_devices, prog=prog,
+        )
+        if asn is not None:
+            out[i] = asn
+        return out
+
+    import jax
+    import jax.numpy as jnp
+
+    Q = SEARCH_ROWS
+    # largest power-of-two device count that divides Q, clamped to the
+    # devices that actually exist
     pool = list(devices) if devices else list(jax.devices())
     D = 1
     avail = min(n_devices, len(pool), Q)
     while D * 2 <= avail:
         D *= 2
-    if D > 1:
-        pkey = (
-            "pmap-vmap", candidates, L, steps, D,
-            tuple(str(d) for d in pool[:D]),
+    for start in range(0, len(live), Q):
+        if should_stop is not None and should_stop():
+            break
+        rows = live[start : start + Q]
+        args, L = _stacked_args(rows, Q)
+        fn = _get_search_fn(candidates, L, steps)
+        args += (
+            jnp.arange(seed + start, seed + start + Q, dtype=jnp.int32),
+            jnp.arange(Q) < len(rows),  # padding rows skip their search
         )
-        pfn = _eval_cache.get(pkey)
-        if pfn is None:
-            pfn = jax.pmap(jax.vmap(fn.raw), devices=pool[:D])
-            _eval_cache[pkey] = pfn
-        chunk = lambda a: a.reshape((D, Q // D) + a.shape[1:])
-        solved, winners = pfn(*(chunk(a) for a in args), chunk(seeds))
-        solved = np.asarray(solved).reshape(Q)
-        winners = np.asarray(winners).reshape((Q,) + winners.shape[2:])
-    else:
-        vkey = ("vmap", candidates, L, steps)
-        vfn = _eval_cache.get(vkey)
-        if vfn is None:
-            vfn = jax.jit(jax.vmap(fn.raw))
-            _eval_cache[vkey] = vfn
-        solved, winners = vfn(*args, seeds)
-        solved = np.asarray(solved)
-        winners = np.asarray(winners)
-
-    for qi, (i, p) in enumerate(live):
-        if bool(solved[qi]):
-            out[i] = _decode_assignment(p, winners[qi], limbs=L)
+        if D > 1:
+            pkey = (
+                "pmap-rows", candidates, L, steps, D,
+                tuple(str(d) for d in pool[:D]),
+            )
+            pfn = _eval_cache.get(pkey)
+            if pfn is None:
+                pfn = jax.pmap(_search_rows(fn.raw), devices=pool[:D])
+                _eval_cache[pkey] = pfn
+            chunk = lambda a: a.reshape((D, Q // D) + a.shape[1:])
+            solved, winners = pfn(*(chunk(a) for a in args))
+            solved = np.asarray(solved).reshape(Q)
+            winners = np.asarray(winners).reshape((Q,) + winners.shape[2:])
+        else:
+            vkey = ("rows", candidates, L, steps)
+            vfn = _eval_cache.get(vkey)
+            if vfn is None:
+                vfn = jax.jit(_search_rows(fn.raw))
+                _eval_cache[vkey] = vfn
+            solved, winners = vfn(*args)
+            solved = np.asarray(solved)
+            winners = np.asarray(winners)
+        for qi, (i, p) in enumerate(rows):
+            if bool(solved[qi]):
+                out[i] = _decode_assignment(p, winners[qi], limbs=L)
     return out
 
 
@@ -1264,6 +1327,7 @@ def device_solve_batch(
     n_devices: int = 1,
     devices=None,
     cube_depth: Optional[int] = None,
+    should_stop=None,
 ) -> List[DeviceVerdict]:
     """The device-FIRST solving funnel for a batch of independent
     queries (ISSUE 9): the accelerator attacks the whole batch before
@@ -1279,7 +1343,7 @@ def device_solve_batch(
        exhaustively evaluated in cube-sized chunks: sat witnesses AND
        device-owned unsat-within-bucket verdicts.
     3. diversified SLS — one batched dispatch of the heterogeneous
-       vmap'd portfolio over everything else.
+       batched portfolio over everything else.
     4. cube-and-conquer — SLS survivors split into 2^depth cubes on
        their top-impact (soft-score gradient) variables; the cube fan
        rides a second batched dispatch, sharded over `devices`.
@@ -1287,13 +1351,16 @@ def device_solve_batch(
     Every sat is host-validated (`validate_witness`) before it
     counts; a corrupted device model degrades to unknown with
     WITNESS_INVALID, never to a wrong verdict.
+
+    `should_stop()`, checked before every search dispatch (stages 3
+    and 4, SEARCH_ROWS queries each) and every cube split, lets an
+    owner winding down skip the work not yet started: a dispatch
+    cannot be interrupted once it runs. Skipped queries stay unknown.
     """
-    from mythril_tpu.laser.batch import ensure_compile_cache
     from mythril_tpu.observe import querylog
 
     if not queries:
         return []
-    ensure_compile_cache()
     if steps is None:
         steps = int(PORTFOLIO_DEFAULTS["first_pass_steps"])
     if cube_depth is None:
@@ -1332,10 +1399,11 @@ def device_solve_batch(
             continue
         sls_live.append((i, prog))
 
-    # stage 3: one diversified-SLS dispatch over the remainder
+    stopped = should_stop or (lambda: False)
+    # stage 3: batched diversified-SLS dispatches over the remainder
     found = _sls_batch(
         sls_live, candidates, steps, seed,
-        n_devices=n_devices, devices=devices,
+        n_devices=n_devices, devices=devices, should_stop=stopped,
     )
     survivors: List[Tuple[int, Program]] = []
     for i, prog in sls_live:
@@ -1350,11 +1418,13 @@ def device_solve_batch(
             )
 
     # stage 4: cube-and-conquer the survivors — 2^depth pinned-bit
-    # cubes per query, fanned in ONE more batched dispatch
+    # cubes per query, fanned in more batched dispatches
     if cube_depth > 0 and survivors:
         cube_live: List[Tuple[int, Program]] = []
         parents: List[int] = []
         for i, prog in survivors:
+            if stopped():
+                return out
             for cq in cube_queries(prog.source, prog, depth=cube_depth):
                 cprog = compile_program(cq)
                 if cprog is None or not cprog.var_slots:
@@ -1364,7 +1434,7 @@ def device_solve_batch(
                 parents.append(i)
         cfound = _sls_batch(
             cube_live, candidates, steps, seed + 7919,
-            n_devices=n_devices, devices=devices,
+            n_devices=n_devices, devices=devices, should_stop=stopped,
         )
         for ci, cprog in cube_live:
             i = parents[ci]
@@ -1417,9 +1487,6 @@ def device_check(
     Callers that already compiled `lowered` pass `prog` to skip the
     recompile (device_check_batch's single-survivor fallback).
     """
-    from mythril_tpu.laser.batch import ensure_compile_cache
-
-    ensure_compile_cache()
     if prog is None:
         prog = compile_program(lowered)
     if prog is None or not prog.var_slots:

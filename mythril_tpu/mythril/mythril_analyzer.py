@@ -19,7 +19,7 @@ from mythril_tpu.analysis.security import fire_lasers, retrieve_callback_issues
 from mythril_tpu.analysis.symbolic import SymExecWrapper
 from mythril_tpu.analysis.traceexplore import get_serializable_statespace
 from mythril_tpu.ethereum.evmcontract import EVMContract
-from mythril_tpu.exceptions import DetectorNotFoundError
+from mythril_tpu.exceptions import DetectorNotFoundError, DevicePrepassError
 from mythril_tpu.laser.execution_info import ExecutionInfo
 from mythril_tpu.laser.smt.solver import SolverStatistics
 from mythril_tpu.mythril.mythril_disassembler import MythrilDisassembler
@@ -207,6 +207,8 @@ class MythrilAnalyzer:
                 deadline=resilience.run_deadline(),
             )
         except Exception:
+            if mode == "always":
+                raise
             log.debug("overlapped corpus prepass unavailable", exc_info=True)
             return None
 
@@ -278,6 +280,13 @@ class MythrilAnalyzer:
         Source().get_source_from_contracts_list(self.contracts)
 
         report = self._build_report(collected, crashes, execution_info)
+        if final:
+            # the corpus prepass's final counters (waves, device steps,
+            # per-device mesh rows): a contract's own execution info may
+            # carry an outcome published mid-run
+            report.meta["device_prepass"] = next(iter(final.values()))[
+                "stats"
+            ]
         # per-run solver attribution (observe/solverstats.py): which
         # engine answered how many queries at what cost — the jsonv2
         # meta view of ROADMAP item 1's device-vs-host question
@@ -396,7 +405,7 @@ class MythrilAnalyzer:
                         )
                         issues = fire_lasers(sym, modules)
                 execution_info = sym.execution_info
-            except DetectorNotFoundError:
+            except (DetectorNotFoundError, DevicePrepassError):
                 raise
             except KeyboardInterrupt:
                 log.critical("Keyboard Interrupt")

@@ -81,8 +81,7 @@ def _kernel(lo_ref, hi_ref, out_lo_ref, out_hi_ref):
         out_hi_ref[i, :] = hi[i]
 
 
-@functools.partial(jax.jit, static_argnames=())
-def _keccak_f_blocks(lo_t, hi_t):
+def _keccak_f_blocks(lo_t, hi_t, interpret):
     """lo_t/hi_t: [25, M] uint32 with M a multiple of BLOCK."""
     from jax.experimental import pallas as pl
 
@@ -98,14 +97,17 @@ def _keccak_f_blocks(lo_t, hi_t):
             jax.ShapeDtypeStruct(lo_t.shape, jnp.uint32),
             jax.ShapeDtypeStruct(hi_t.shape, jnp.uint32),
         ],
-        interpret=jax.default_backend() == "cpu",
+        interpret=interpret,
     )(lo_t, hi_t)
 
 
-@jax.jit
-def keccak_f_pallas(lo, hi):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def keccak_f_pallas(lo, hi, interpret: bool = False):
     """keccak-f[1600] on [..., 25] uint32 lane pairs via the pallas
-    kernel. Shape-compatible with ops.keccak.keccak_f."""
+    kernel. Shape-compatible with ops.keccak.keccak_f. `interpret` runs
+    the Pallas interpreter instead of the Mosaic kernel: CPU callers
+    (tests) ask for it; it is never inferred from the backend, so a
+    compile for a described chip compiles the real kernel."""
     batch_shape = lo.shape[:-1]
     n = int(np.prod(batch_shape)) if batch_shape else 1
     m = ((n + BLOCK - 1) // BLOCK) * BLOCK
@@ -115,7 +117,7 @@ def keccak_f_pallas(lo, hi):
     lo_t = lo_t.at[:, :n].set(lo.reshape(n, 25).T)
     hi_t = hi_t.at[:, :n].set(hi.reshape(n, 25).T)
 
-    out_lo, out_hi = _keccak_f_blocks(lo_t, hi_t)
+    out_lo, out_hi = _keccak_f_blocks(lo_t, hi_t, interpret)
     out_lo = out_lo[:, :n].T.reshape(batch_shape + (25,))
     out_hi = out_hi[:, :n].T.reshape(batch_shape + (25,))
     return out_lo, out_hi

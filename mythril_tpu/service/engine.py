@@ -608,11 +608,9 @@ class AnalysisEngine:
     tests pin without paying a kernel compile."""
 
     def __init__(self, config: Optional[ServiceConfig] = None) -> None:
-        from mythril_tpu.laser.batch import ensure_compile_cache
         from mythril_tpu.laser.batch.seeds import code_cap_bucket
         from mythril_tpu.support.resilience import DegradationLog
 
-        ensure_compile_cache()
         self.cfg = config or ServiceConfig()
         self.queue = JobQueue(self.cfg.queue_capacity)
         #: device-group mesh (myth serve --devices N): the arena
@@ -1872,6 +1870,15 @@ class AnalysisEngine:
             return self._block(device)
         return self._fuse(device)
 
+    def _donation_ok(self) -> bool:
+        """Buffer donation: a seeded wave batch is never read again on
+        the host (retries rebuild it from `calldata`), so the device
+        reuses its buffers for the output. CPU ignores donation with a
+        warning, so it is gated to accelerators."""
+        import jax
+
+        return jax.default_backend() != "cpu"
+
     def _wave_kernel(self, job_ids, batch, table, donate) -> Optional[Tuple]:
         """(kernel, phases) for this wave, or None for a generic wave.
 
@@ -2105,18 +2112,12 @@ class AnalysisEngine:
             "t0": time.perf_counter(),
         }
         try:
-            import jax
-
             resilience.inject("service.dispatch")
             with trace(
                 "service.wave.dispatch", track="service",
                 jobs=len(wave_inputs),
             ):
-                # buffer donation: the seeded batch is never read again
-                # on the host (retries rebuild it from `calldata`), so
-                # the device reuses its buffers for the output. CPU
-                # ignores donation with a warning, so gate it.
-                donate = jax.default_backend() != "cpu"
+                donate = self._donation_ok()
                 table = self._table()
                 spec = self._wave_kernel(wave_inputs, batch, table, donate)
                 if spec is not None:
@@ -2164,7 +2165,7 @@ class AnalysisEngine:
         from mythril_tpu.laser.batch.state import make_batch
         from mythril_tpu.support import resilience
 
-        donate = jax.default_backend() != "cpu"
+        donate = self._donation_ok()
         record: Dict = {
             "wave_inputs": wave_inputs,
             "code_ids": code_ids,

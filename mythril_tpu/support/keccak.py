@@ -12,6 +12,7 @@ path (mythril_tpu/native/keccak.cpp) loaded over ctypes when built.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 
 _ROT = [
@@ -84,15 +85,19 @@ def _load_native():
     global _native
     if _native is not None:
         return _native
-    so = os.path.join(os.path.dirname(__file__), "..", "native", "libmythril_native.so")
+    from mythril_tpu.native import library_path
+
     try:
-        lib = ctypes.CDLL(os.path.abspath(so))
+        lib = ctypes.CDLL(library_path())
         lib.mtpu_keccak256.argtypes = [
             ctypes.c_char_p, ctypes.c_size_t, ctypes.c_char_p,
         ]
         lib.mtpu_keccak256.restype = None
         _native = lib
     except OSError:
+        logging.getLogger(__name__).warning(
+            "native keccak unavailable; using pure Python", exc_info=True
+        )
         _native = False
     return _native
 

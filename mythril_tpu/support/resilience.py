@@ -81,6 +81,10 @@ class DegradationReason:
     INTERRUPTED = "interrupted"
     CONTRACT_SKIPPED = "contract-skipped"
     PREPASS_FAILED = "prepass-failed"
+    #: the corpus prepass was still inside a device dispatch when its
+    #: owner finished (analysis/corpus.py OverlappedPrepass.finish):
+    #: the outcomes its last harvested wave published stand as final
+    PREPASS_CUT = "prepass-cut"
     #: a poison job — implicated in repeated wave faults (in-process
     #: strike counter fed by wave-fault attribution plus a
     #: crash-implication strike at journal recovery) — was isolated to

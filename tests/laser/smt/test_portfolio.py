@@ -138,7 +138,7 @@ def test_batched_matches_single():
 
 @pytest.mark.slow
 def test_batched_dispatch_sharded_over_devices():
-    """The query axis shards over a device mesh (pmap of the vmapped
+    """The query axis shards over a device mesh (pmap of the batched
     search): same aligned answers, each device solving its chunk."""
     import jax
 

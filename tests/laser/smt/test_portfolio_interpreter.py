@@ -28,6 +28,13 @@ OPS = {
     "sub": terms.sub,
     "mul": terms.mul,
     "udiv": terms.udiv,
+    # lowered to a shift (compile_program): the selector pattern
+    "udiv-pow2": lambda a, b: terms.add(
+        terms.udiv(a, terms.bv_const(1 << 24, W)), b
+    ),
+    "udiv-1": lambda a, b: terms.add(
+        terms.udiv(a, terms.bv_const(1, W)), b
+    ),
     "urem": terms.urem,
     "and": terms.bvand,
     "or": terms.bvor,
@@ -74,3 +81,15 @@ def test_device_op_matches_host(name):
         prog_ne = compile_program([terms.eq(expr, terms.bv_const(wrong, W))])
         solved_wrong, _ = debug_eval(prog_ne, asn)
         assert not solved_wrong, f"{name}({xv},{yv}): device accepts wrong value"
+
+
+def test_udiv_by_power_of_two_compiles_to_a_shift():
+    from mythril_tpu.laser.smt.solver.portfolio import OP_INDEX
+
+    x = terms.bv_var("dp_pow2_x", 256)
+    sel = terms.udiv(x, terms.bv_const(1 << 224, 256))
+    prog = compile_program([terms.eq(sel, terms.bv_const(0xA9059CBB, 256))])
+    ops = set(prog.opcodes[: prog.n_real_nodes].tolist())
+    assert OP_INDEX["udiv"] not in ops and OP_INDEX["extract"] in ops
+    solved, _ = debug_eval(prog, {"dp_pow2_x": 0xA9059CBB << 224 | 5})
+    assert solved

@@ -359,3 +359,32 @@ def test_extcodesize_and_returndatacopy_device_semantics():
     out2, _ = run(make_batch(1, calldata=[b""]), make_code_table([code2]),
                   max_steps=8)
     assert int(out2.status[0]) == Status.UNSUPPORTED
+
+
+def test_einsum_peek_matches_gather_peek(monkeypatch):
+    """Accelerators read stack slots, calldata and storage through
+    one-hot contractions ("einsum"); CPU takes the gather. Both forms
+    are traced here on CPU over a loop of CALLDATALOAD / arithmetic /
+    SSTORE / SLOAD and must leave every lane bit-identical."""
+    import importlib
+
+    import jax
+
+    from __graft_entry__ import _demo_workload
+    from mythril_tpu.laser.batch.run import _run_impl
+
+    # the package re-exports the step FUNCTION under the module's name
+    step_mod = importlib.import_module("mythril_tpu.laser.batch.step")
+
+    batch, code = _demo_workload(n_lanes=16)
+    outs = {}
+    for choice in ("gather", "einsum"):
+        monkeypatch.setattr(step_mod, "_PEEK_CHOICE", choice)
+        # a fresh jit per choice: the choice is read at trace time
+        outs[choice] = jax.jit(lambda b, c: _run_impl(b, c, max_steps=48))(
+            batch, code
+        )
+    for got, want in zip(
+        jax.tree.leaves(outs["einsum"]), jax.tree.leaves(outs["gather"])
+    ):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

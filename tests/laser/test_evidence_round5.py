@@ -101,9 +101,16 @@ def test_outcome_owns_requires_final_and_complete():
     assert _outcome_owns({"device_complete": True, "stats": {}})
 
 
-def test_ownership_end_to_end_matches_host_walk():
+def test_ownership_end_to_end_matches_host_walk(monkeypatch):
     """analyze_corpus with ownership: the owned result's distinct
     findings equal the host-only walk's on the same contract."""
+    from mythril_tpu.support.support_args import args
+
+    # the harness's host-first funnel (conftest), whatever an analyzer
+    # built earlier in this process pushed into the global args: the
+    # device funnel's time on the CPU (26 s here) would eat the prepass
+    # budget
+    monkeypatch.setattr(args, "device_first", False)
     rows = [(WRAP_AND_ORIGIN, "", "w")]
     dev = analyze_corpus(
         rows,

@@ -306,3 +306,56 @@ def test_service_pipeline_overlaps_waves_from_distinct_jobs():
             assert job.report["device"]["waves"] >= 1
     finally:
         engine.close()
+
+
+# -- buffer donation (accelerator-only by default) ---------------------------
+def test_explorer_donated_waves_match_undonated(monkeypatch):
+    """Off CPU the explorer dispatches the donated kernels (donated
+    wave + donated on-device reseed). CPU gates donation off, so the
+    donated path is forced here: it must explore exactly like the
+    undonated one."""
+    kw = dict(
+        lanes_per_contract=8,
+        waves=3,
+        steps_per_wave=64,
+        transaction_count=2,
+        pipeline=True,
+        seed=3,
+    )
+    plain = DeviceCorpusExplorer([BRANCHER], **kw).run()
+    monkeypatch.setattr(
+        DeviceCorpusExplorer, "_donation_ok", lambda self: True
+    )
+    donated = DeviceCorpusExplorer([BRANCHER], **kw).run()
+    assert donated["stats"]["waves"] >= 2
+    assert _fingerprint(donated["contracts"][0]) == _fingerprint(
+        plain["contracts"][0]
+    )
+
+
+def test_service_donated_wave_settles(monkeypatch):
+    """A service wave through the donated kernel (forced on CPU) settles
+    its job with the device findings."""
+    from mythril_tpu.service.engine import AnalysisEngine, ServiceConfig
+    from mythril_tpu.service.jobs import Job
+
+    monkeypatch.setattr(AnalysisEngine, "_donation_ok", lambda self: True)
+    engine = AnalysisEngine(
+        ServiceConfig(
+            stripes=2,
+            lanes_per_stripe=4,
+            steps_per_wave=64,
+            max_waves=2,
+            host_walk=False,
+            coalesce_wait_s=0.05,
+            idle_wait_s=0.02,
+        )
+    ).start()
+    try:
+        job = engine.submit(Job(KILLABLE))
+        settled = engine.queue.wait_terminal(job.id, timeout_s=120.0)
+        assert settled is not None and settled.state == "done"
+        assert job.report["device"]["waves"] >= 1
+        assert job.report["device"]["triggers"]["selfdestruct"] >= 1
+    finally:
+        engine.close()

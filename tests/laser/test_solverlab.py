@@ -36,11 +36,16 @@ def _no_capture_leak():
 def _capture_fault_suite(out_dir) -> list:
     from mythril_tpu.laser.batch.explore import DeviceCorpusExplorer
     from mythril_tpu.support.model import clear_cache
+    from mythril_tpu.support.support_args import args as support_args
 
     # the get_model memo would swallow repeat queries before they
     # reach check_terms (and so the capture hook); every capture run
     # starts from a cold memo, exactly like a fresh process
     clear_cache()
+    # the harness's host-first funnel (conftest): a MythrilAnalyzer built
+    # earlier in this process pushes device_first=True into the global
+    # args, and a device-won flip carries no loss reason
+    saved, support_args.device_first = support_args.device_first, False
     querylog.configure_capture(str(out_dir))
     try:
         ex = DeviceCorpusExplorer(
@@ -54,6 +59,7 @@ def _capture_fault_suite(out_dir) -> list:
         ex.run()
     finally:
         querylog.configure_capture(None)
+        support_args.device_first = saved
     return querylog.load_corpus(str(out_dir))
 
 

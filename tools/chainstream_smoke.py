@@ -171,10 +171,6 @@ def make_endpoint(chain: ScriptedChain):
 # the watcher child (real `myth watch` through the real CLI)
 # ---------------------------------------------------------------------------
 def child_main(args) -> int:
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
     from mythril_tpu.interfaces.cli import main as cli_main
 
     argv = ["myth", "watch", "--state", args.state,
@@ -202,6 +198,7 @@ def spawn_watcher(state: str, urls, recover=False):
     return subprocess.Popen(
         cmd, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
         text=True, cwd=str(Path(__file__).resolve().parent.parent),
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
     )
 
 

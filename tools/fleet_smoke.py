@@ -59,12 +59,9 @@ FRESH = "6000356000556000ff"  # calldata -> storage, selfdestruct
 
 
 def child_main(args) -> int:
-    import jax
+    from mythril_tpu.support.accel import ensure_compile_cache
 
-    jax.config.update("jax_platforms", "cpu")
-    os.makedirs("/tmp/mtpu_xla_cache", exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", "/tmp/mtpu_xla_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    ensure_compile_cache()
 
     from mythril_tpu.service.engine import ServiceConfig
     from mythril_tpu.service.server import AnalysisServer
@@ -101,6 +98,7 @@ def spawn_replica(store: str):
         ],
         stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
         text=True, cwd=str(Path(__file__).resolve().parent.parent),
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
     )
     deadline = time.monotonic() + 120.0
     url = None

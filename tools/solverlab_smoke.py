@@ -42,14 +42,12 @@ GATED = "60003560f81c604214600d57005b600160005500"
 def main() -> int:
     import os
 
-    import jax
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from mythril_tpu.support.accel import ensure_compile_cache
 
-    jax.config.update("jax_platforms", "cpu")
     # persistent XLA cache: the inverted-funnel leg compiles the
     # batched diversified-search kernel once per shape class
-    os.makedirs("/tmp/mtpu_xla_cache", exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", "/tmp/mtpu_xla_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    ensure_compile_cache()
 
     from mythril_tpu import observe
     from mythril_tpu.analysis import solverlab
