@@ -1,0 +1,9 @@
+"""Share of the traced slice in which no operation ran on the device:
+1 - (union of device operation intervals / slice length)."""
+
+
+def read(run):
+    trace = run.get("trace") or {}
+    if not trace.get("window_s"):
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
