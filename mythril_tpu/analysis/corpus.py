@@ -1234,6 +1234,9 @@ def _analyze_one(payload: Tuple) -> Dict:
             "device_prepass": exploration["stats"] if exploration else None,
             "phases": PhaseProfile().as_dict(),
             "precovered_skips": sym.laser.device_precovered_skips,
+            # the budget that cut the walk ("execution" or "create"),
+            # None when it ran to its end
+            "cut": sym.laser.budget_cut,
             "wall_s": round(time.perf_counter() - t_start, 3),
             "error": None,
         }

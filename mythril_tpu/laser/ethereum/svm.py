@@ -94,6 +94,10 @@ class LaserEVM:
             self.edges = self._recorder.edges
 
         self.time: Optional[datetime] = None
+        #: the budget that ended a walk early ("execution" or "create"):
+        #: set where `exec` returns for it, or where the solver's refusal
+        #: for it drops states (`_note_budget_drops`); None while none has
+        self.budget_cut: Optional[str] = None
 
         # device-prepass coverage guide: branch directions the device
         # explorer concretely executed for this runtime code. Forks
@@ -187,6 +191,7 @@ class LaserEVM:
             feasible = [
                 ws for ws in self.open_states if ws.constraints.is_possible
             ]
+            self._note_budget_drops(len(feasible) < len(self.open_states))
             if len(feasible) < len(self.open_states):
                 log.info(
                     "Pruned %d unreachable states",
@@ -206,15 +211,26 @@ class LaserEVM:
     # ------------------------------------------------------------------
     # time budget
     # ------------------------------------------------------------------
-    def _out_of_time(self, creating: bool) -> bool:
+    def _spent_budget(self, creating: bool) -> Optional[str]:
+        """The name of the time budget that has run out ("create" or
+        "execution"), or None."""
         if creating and self.open_states:
-            budget = self.create_timeout
+            name, budget = "create", self.create_timeout
         else:
-            budget = self.execution_timeout
-        return (
-            budget > 0
-            and self.time + timedelta(seconds=budget) <= datetime.now()
-        )
+            name, budget = "execution", self.execution_timeout
+        if budget > 0 and self.time + timedelta(seconds=budget) <= datetime.now():
+            return name
+        return None
+
+    def _note_budget_drops(self, dropped: bool) -> None:
+        """Record the execution budget as the walk's cut when a
+        feasibility filter dropped states after the solver's share of
+        it ran out: get_model then refuses every query with
+        SolverTimeOutException, an UnsatError, so `is_possible` reads
+        false for states that were never shown unreachable. This is
+        how most walks meet their budget, before `exec`'s own check."""
+        if dropped and time_handler.solver_budget_spent():
+            self.budget_cut = self.budget_cut or "execution"
 
     # ------------------------------------------------------------------
     # the hot loop
@@ -222,8 +238,10 @@ class LaserEVM:
     def exec(self, create=False, track_gas=False) -> Optional[List[GlobalState]]:
         finals: List[GlobalState] = []
         for state in self.strategy:
-            if self._out_of_time(create):
-                log.debug("Hit the time budget, returning.")
+            spent = self._spent_budget(create)
+            if spent is not None:
+                log.debug("Hit the %s time budget, returning.", spent)
+                self.budget_cut = self.budget_cut or spent
                 return finals + [state] if track_gas else None
 
             try:
@@ -235,12 +253,14 @@ class LaserEVM:
 
             if args.sparse_pruning is False:
                 with self._phases.measure("feasibility"):
-                    successors = [
+                    feasible = [
                         s
                         for s in successors
                         if self._device_precovered(s)
                         or s.world_state.constraints.is_possible
                     ]
+                self._note_budget_drops(len(feasible) < len(successors))
+                successors = feasible
 
             self._recorder.observe(opcode, successors)
             if successors:

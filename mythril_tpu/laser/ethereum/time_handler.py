@@ -11,6 +11,10 @@ import time
 
 from mythril_tpu.support.support_utils import Singleton
 
+#: what the solver keeps back of the budget: get_model refuses a query
+#: (SolverTimeOutException) once less than this is left
+SOLVER_MARGIN_MS = 500
+
 
 class TimeHandler(object, metaclass=Singleton):
     def __init__(self):
@@ -26,6 +30,10 @@ class TimeHandler(object, metaclass=Singleton):
         if self.start_time is None:
             return 2**31
         return self.execution_time - (int(time.time() * 1000) - self.start_time)
+
+    def solver_budget_spent(self) -> bool:
+        """True once get_model refuses every query for the budget."""
+        return self.time_remaining() - SOLVER_MARGIN_MS <= 0
 
 
 time_handler = TimeHandler()

@@ -19,11 +19,20 @@ and a pipelined multi-device run renders as an actual timeline — one
 track per device group / thread, wave execution against host harvest,
 bubbles and compile stalls visible as gaps.
 
+Each `trace()` span is also a JAX profiler annotation (a TraceMe) once
+the process has imported jax: under `jax.profiler.trace` it lands on
+the profiler's host plane, on the same clock as the device's `XLA Ops`,
+so a device idle stretch can be matched to the host span that was open
+across it. This module never imports jax itself (host-only CLI paths
+and pool workers do not load it), and retrospective spans (`add`) stay
+ring-only: the device plane already holds the device's side.
+
 Span taxonomy (docs/observability.md has the diagram):
 
     job > contract > explore.run > phase > {wave.dispatch, wave.device,
     wave.harvest, wave.consume, flip.solve.host, flip.solve.device,
     kernel.compile, mesh.chunk, mesh.steal, service.wave}
+    service.host.lock_wait, service.host.walk > contract.analyze
 """
 
 from __future__ import annotations
@@ -31,6 +40,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -144,27 +154,50 @@ def _stack() -> List[int]:
     return stack
 
 
+_ANNOTATION = None
+
+
+def _annotation_class():
+    """`jax.profiler.TraceAnnotation` once jax is imported, else None.
+    Looked up, never imported: a process that has not loaded jax pays
+    nothing for it."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        _ANNOTATION = getattr(profiler, "TraceAnnotation", None)
+    return _ANNOTATION
+
+
 class _TraceCtx:
     """The `trace()` context manager: pushes a span id on the thread's
     stack at entry (so children see their parent), records the closed
-    span at exit. Exceptions propagate; the span still closes and is
-    marked with the exception type."""
+    span at exit, and holds the span's profiler annotation open in
+    between. Exceptions propagate; the span still closes and is marked
+    with the exception type."""
 
-    __slots__ = ("name", "track", "attrs", "sid", "t0")
+    __slots__ = ("name", "track", "attrs", "sid", "t0", "annotation")
 
     def __init__(self, name: str, track: Optional[str], attrs: Dict) -> None:
         self.name = name
         self.track = track
         self.attrs = attrs
+        self.annotation = None
 
     def __enter__(self) -> "_TraceCtx":
         self.sid = next(_IDS)
+        cls = _annotation_class()
+        if cls is not None:
+            self.annotation = cls(self.name, **(self.attrs or {}))
+            self.annotation.__enter__()
         self.t0 = time.perf_counter()
         _stack().append(self.sid)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         t1 = time.perf_counter()
+        if self.annotation is not None:
+            self.annotation.__exit__(exc_type, exc, tb)
         stack = _stack()
         if stack and stack[-1] == self.sid:
             stack.pop()

@@ -2602,22 +2602,40 @@ class AnalysisEngine:
             job.journey_id, journey.TIER_HOST_WALK, "start",
             timeout_s=timeout,
         )
-        solver_before = observe.solver_marker()
+        # host symbolic state (term arena, CDCL session) is
+        # process-global: in-process workers serialize here. The wait
+        # is a span of its own and `locked` ends it on the journey;
+        # `done` is recorded before the release, so a walk's locked to
+        # done holds its own work and nothing else
+        with trace("service.host.lock_wait", track="service", job=job.id):
+            HOST_SYMBOLIC_LOCK.acquire()
         try:
-            # host symbolic state (term arena, CDCL session) is
-            # process-global: in-process workers serialize here
-            with HOST_SYMBOLIC_LOCK:
+            observe.journey_event(
+                job.journey_id, journey.TIER_HOST_WALK, "locked"
+            )
+            solver_before = observe.solver_marker()
+            try:
                 with trace(
                     "service.host.walk", track="service", job=job.id
                 ):
                     result = analyze_one_payload(payload)
-        except CancelledError:
-            raise
-        except Exception as why:  # analyze_one_payload already catches;
-            result = {"issues": [], "states": 0, "error": str(why)}
-        # the walk ran under HOST_SYMBOLIC_LOCK, so the attribution
-        # delta is this job's: the ladder hops (device-first vs CDCL)
-        # land on the timeline as one solver-tier event
+            except CancelledError:
+                raise
+            except Exception as why:  # analyze_one_payload already catches;
+                result = {"issues": [], "states": 0, "error": str(why)}
+            self._walk_done(job, result, solver_before)
+        finally:
+            HOST_SYMBOLIC_LOCK.release()
+        self._host_inflight.pop(job.id, None)
+        self._c_host_completed.inc()
+        self._finalize(job, track, outcome, host_result=result)
+
+    def _walk_done(self, job: Job, result: Dict, solver_before) -> None:
+        """The walk's journey events, under HOST_SYMBOLIC_LOCK: the
+        solver attribution delta and the phase split are this walk's
+        alone (walks hold the lock; device-only waves run no solver or
+        LASER step). The ladder hops (device-first vs CDCL) land as one
+        solver-tier event; `done` carries the budget cut and the split."""
         try:
             attribution = observe.solver_attribution(solver_before)
             if attribution:
@@ -2630,14 +2648,23 @@ class AnalysisEngine:
                 )
         except Exception:
             log.debug("journey solver attribution failed", exc_info=True)
+        phases = result.get("phases") or {}
+
+        def phase(name: str, field: str = "wall_s"):
+            return phases.get(name, {}).get(field, 0)
+
         observe.journey_event(
             job.journey_id, journey.TIER_HOST_WALK, "done",
             issues=len(result.get("issues") or ()),
             states=result.get("states", 0),
+            cut=bool(result.get("cut")),
+            cut_budget=result.get("cut"),
+            step_s=phase("step"),
+            feasibility_s=phase("feasibility"),
+            solve_s=phase("solve"),
+            solve_n=phase("solve", "count"),
+            concretize_s=phase("concretize"),
         )
-        self._host_inflight.pop(job.id, None)
-        self._c_host_completed.inc()
-        self._finalize(job, track, outcome, host_result=result)
 
     def _finalize(
         self, job: Job, track: Optional[_JobTrack], outcome: Dict,

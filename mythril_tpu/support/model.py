@@ -14,7 +14,7 @@ from collections import OrderedDict
 from typing import Dict, Iterable, Tuple
 
 from mythril_tpu.exceptions import SolverTimeOutException, UnsatError
-from mythril_tpu.laser.ethereum.time_handler import time_handler
+from mythril_tpu.laser.ethereum.time_handler import SOLVER_MARGIN_MS, time_handler
 from mythril_tpu.laser.smt import Bool
 from mythril_tpu.laser.smt.model import Model
 from mythril_tpu.laser.smt.solver import Optimize, sat, unknown, unsat
@@ -65,7 +65,7 @@ def get_model(
 
     timeout = solver_timeout or args.solver_timeout
     if enforce_execution_time:
-        timeout = min(timeout, time_handler.time_remaining() - 500)
+        timeout = min(timeout, time_handler.time_remaining() - SOLVER_MARGIN_MS)
         if timeout <= 0:
             raise SolverTimeOutException("Execution time budget exhausted")
 

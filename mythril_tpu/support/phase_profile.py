@@ -26,12 +26,31 @@ do solver calls cost" separately rather than summing to the total.
 from __future__ import annotations
 
 import time
-from contextlib import contextmanager
 from typing import Dict, Tuple
 
+from mythril_tpu.observe.registry import registry
 from mythril_tpu.support.support_utils import Singleton
 
 _METRIC_NAME = "mtpu_phase_wall_seconds"
+
+
+class _Measure:
+    """One timed block: a `perf_counter` pair around the body, observed
+    into the phase's series at exit (exceptions included). A plain
+    class rather than a generator context manager: LASER enters two of
+    these per instruction step."""
+
+    __slots__ = ("_child", "_t0")
+
+    def __init__(self, child) -> None:
+        self._child = child
+
+    def __enter__(self) -> "_Measure":
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self._child.observe(time.perf_counter() - self._t0)
 
 
 class PhaseProfile(object, metaclass=Singleton):
@@ -44,6 +63,9 @@ class PhaseProfile(object, metaclass=Singleton):
     def __init__(self) -> None:
         self._backing_reg = None
         self._backing_hist = None
+        #: phase -> the histogram's series handle, resolved once per
+        #: backing registry (cleared by `_rebind`)
+        self._children: Dict = {}
         self._marker: Dict[str, Tuple[float, int]] = {}
         self.reset()
 
@@ -52,16 +74,18 @@ class PhaseProfile(object, metaclass=Singleton):
         """The backing registry histogram, re-resolved when the
         registry instance changes (reset_registry in tests) — this
         singleton outlives any one registry."""
-        from mythril_tpu.observe.registry import registry
-
         reg = registry()
-        if self._backing_hist is None or self._backing_reg is not reg:
-            self._backing_reg = reg
-            self._backing_hist = reg.histogram(
-                _METRIC_NAME,
-                "host analysis wall seconds per pipeline phase",
-            )
+        if self._backing_reg is not reg:
+            self._rebind(reg)
         return self._backing_hist
+
+    def _rebind(self, reg) -> None:
+        self._backing_reg = reg
+        self._backing_hist = reg.histogram(
+            _METRIC_NAME,
+            "host analysis wall seconds per pipeline phase",
+        )
+        self._children = {}
 
     # -- the backing totals (process-cumulative) -----------------------
     def _totals(self) -> Dict[str, Tuple[float, int]]:
@@ -98,15 +122,18 @@ class PhaseProfile(object, metaclass=Singleton):
                 out[phase] = total_n - base
         return out
 
-    @contextmanager
-    def measure(self, phase: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self._hist.labels(phase=phase).observe(
-                time.perf_counter() - t0
+    def measure(self, phase: str) -> _Measure:
+        """Time the `with` body into the phase's series. The series
+        handle is resolved once per phase and backing registry."""
+        reg = registry()  # `_hist`'s check inlined: LASER's per-step path
+        if self._backing_reg is not reg:
+            self._rebind(reg)
+        child = self._children.get(phase)
+        if child is None:
+            child = self._children[phase] = self._backing_hist.labels(
+                phase=phase
             )
+        return _Measure(child)
 
     def add(self, phase: str, seconds: float, n: int = 1) -> None:
         self._hist.labels(phase=phase).add_raw(seconds, n)

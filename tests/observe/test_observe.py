@@ -548,6 +548,33 @@ def test_phase_profile_view_and_registry_backing():
     assert hist.labels(phase="obs_test").count == before + 3
 
 
+def test_phase_profile_measure_follows_a_registry_swap():
+    import importlib
+
+    from mythril_tpu.support.phase_profile import PhaseProfile
+
+    registry_module = importlib.import_module("mythril_tpu.observe.registry")
+
+    profile = PhaseProfile()
+    with profile.measure("obs_swap"):  # caches the old registry's handle
+        pass
+    old = registry_module._REGISTRY
+    fresh = registry_module.reset_registry()
+    try:
+        with profile.measure("obs_swap"):
+            time.sleep(0.001)
+        series = fresh.histogram("mtpu_phase_wall_seconds").labels(
+            phase="obs_swap"
+        )
+        assert series.count == 1
+        assert series.sum >= 0.001
+        assert fresh.snapshot()["mtpu_phase_wall_seconds"][
+            (("phase", "obs_swap"),)
+        ]["count"] == 1
+    finally:
+        registry_module._REGISTRY = old
+
+
 # ---------------------------------------------------------------------------
 # registry-vs-legacy equality on a real explorer run
 # ---------------------------------------------------------------------------

@@ -23,8 +23,10 @@ import pytest
 from mythril_tpu import observe
 from mythril_tpu.analysis.corpusgen import clean_contract
 from mythril_tpu.analysis.static import analysis_config_fingerprint
+from mythril_tpu.observe.registry import registry
 from mythril_tpu.service.client import ServiceClient
-from mythril_tpu.service.engine import ServiceConfig
+from mythril_tpu.service.engine import AnalysisEngine, ServiceConfig
+from mythril_tpu.service.jobs import Job
 from mythril_tpu.service.server import AnalysisServer
 from mythril_tpu.store import close_stores, code_hash_hex, open_store
 from mythril_tpu.support.support_args import args as support_args
@@ -207,3 +209,95 @@ def test_healthz_readiness_split_and_draining_reason():
         assert refusal.value.payload["not_ready_reasons"]
     finally:
         srv.close()
+
+
+# ---------------------------------------------------------------------------
+# the host walk on the journey: lock wait, walk, budget cut, phase split
+# ---------------------------------------------------------------------------
+WALK_CFG = dict(CFG, host_walk=True, host_workers=2, execution_timeout=10)
+
+
+def writer(value: int) -> str:
+    """WRITER with its first stored constant replaced: distinct codes."""
+    return "60%02x" % value + WRITER[4:]
+
+
+def walk_rows(job: Job) -> dict:
+    rows = observe.journey_log().events(job.journey_id)
+    out = {}
+    for row in rows:
+        if row["tier"] == "host-walk":
+            assert row["event"] not in out, rows  # one walk per job
+            out[row["event"]] = row
+    return out
+
+
+def settle(engine: AnalysisEngine, jobs) -> None:
+    for job in jobs:
+        settled = engine.queue.wait_terminal(job.id, 180.0)
+        assert settled is not None and settled.state == "done", job.id
+
+
+def solve_count() -> int:
+    return registry().histogram("mtpu_phase_wall_seconds").labels(
+        phase="solve"
+    ).count
+
+
+def test_host_walk_journey_lock_wait_walk_and_split():
+    engine = AnalysisEngine(ServiceConfig(**WALK_CFG)).start()
+    try:
+        jobs = [engine.submit(Job(code)) for code in (KILLABLE, writer(2))]
+        settle(engine, jobs)
+        walks = [walk_rows(job) for job in jobs]
+        for walk in walks:
+            start, locked, done = (
+                walk[event]["t"] for event in ("start", "locked", "done")
+            )
+            assert start <= locked <= done
+            # wait and walk add up to the whole span, from one clock
+            assert (locked - start) + (done - locked) == pytest.approx(
+                done - start
+            )
+            attrs = walk["done"]["attrs"]
+            assert attrs["cut"] is False
+            assert "cut_budget" not in attrs
+            assert attrs["solve_s"] >= 0.0 and attrs["solve_n"] >= 1
+            assert {"step_s", "feasibility_s", "concretize_s"} <= set(attrs)
+        # the lock serializes walks: the later one is granted it only
+        # once the earlier one is done
+        first, second = sorted(walks, key=lambda w: w["locked"]["t"])
+        assert second["locked"]["t"] >= first["done"]["t"]
+        # both spans are on the flight recorder for each job
+        for job in jobs:
+            names = {
+                s.name for s in observe.flight_recorder().tail(4096)
+                if (s.attrs or {}).get("job") == job.id
+            }
+            assert {"service.host.lock_wait", "service.host.walk"} <= names
+    finally:
+        engine.close()
+
+
+def test_waves_add_nothing_to_a_walks_solver_split():
+    engine = AnalysisEngine(ServiceConfig(**WALK_CFG)).start()
+    try:
+        # device-only jobs alone run no solver query
+        before = solve_count()
+        settle(engine, [
+            engine.submit(Job(writer(v), host_walk=False)) for v in (3, 4)
+        ])
+        assert solve_count() == before
+        # a walk with waves dispatching beside it: every solve in the
+        # process is the walk's, and its `done` counts all of them
+        before = solve_count()
+        walked = engine.submit(Job(writer(5)))
+        waves = [
+            engine.submit(Job(writer(v), host_walk=False)) for v in (6, 7, 8)
+        ]
+        settle(engine, [walked] + waves)
+        attrs = walk_rows(walked)["done"]["attrs"]
+        assert attrs["solve_n"] >= 1
+        assert solve_count() - before == attrs["solve_n"]
+    finally:
+        engine.close()
