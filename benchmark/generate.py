@@ -11,7 +11,8 @@ and how many contracts it adds:
 - `loop`: `caps` cycles through the loop caps; the assert's 4-byte
   magic is drawn from the seed;
 - `fixture_mutant`: the vendored fixtures round-robin by family, each a
-  constant mutant drawn from the seed.
+  constant mutant drawn from the seed; `order` names the families in
+  the order of the round (those it leaves out follow by name).
 
 `corpus(mix, seed, index)` is the index-th corpus of a stream of
 corpora that differ only in their constants; `stream(mix, seed)`
@@ -38,6 +39,8 @@ def _draw(part: Dict, k: int, rng: random.Random, families) -> Tuple[str, str]:
         cap = part["caps"][k % len(part["caps"])]
         return f"loop{cap}", contracts.loop_contract(cap, rng.getrandbits(32))
     if shape == "fixture_mutant":
+        rank = {name: i for i, name in enumerate(part.get("order", ()))}
+        families = sorted(families, key=lambda f: (rank.get(f[0], len(rank)), f[0]))
         family, code = families[k % len(families)]
         mutant = contracts.mutate_constants(bytes.fromhex(code), rng)
         return family, mutant.hex()

@@ -1,6 +1,8 @@
-"""Median seconds a walked job waited for the host symbolic lock, from
-its journey (host-walk start to host-walk locked). A program that
-records no `locked` event gives nothing to read."""
+"""Median seconds a walked job waited before its walk began its own
+work, from its journey (host-walk start to host-walk locked): the wait
+for the host symbolic lock where walks take turns on it, for a free
+walker where they run side by side. A program that records no
+`locked` event gives nothing to read."""
 
 from harness import quantile
 

@@ -1,7 +1,8 @@
-"""Median seconds of a walk holding the host symbolic lock, from its
-journey (host-walk locked to host-walk done): the walk without its wait
-for the lock. A program that records no `locked` event gives nothing
-to read."""
+"""Median seconds of a walk doing its own work, from its journey
+(host-walk locked, where the walk has begun its own work, to host-walk
+done): the walk without its wait to begin, whether walks take turns on
+the host symbolic lock or run side by side. A program that records no
+`locked` event gives nothing to read."""
 
 from harness import quantile
 

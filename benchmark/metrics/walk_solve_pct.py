@@ -1,7 +1,9 @@
 """Percent of host-walk time spent in the solver: the walks' `solve_s`
 (every get_model call of the walk, from its journey `done` event) over
-their time holding the host symbolic lock (locked to done). A program
-that records no `locked` event or no `solve_s` gives nothing to read."""
+their time doing their own work (locked, where a walk has begun it, to
+done), whether walks take turns on the host symbolic lock or run side
+by side. A program that records no `locked` event or no `solve_s`
+gives nothing to read."""
 
 
 def read(run):

@@ -102,7 +102,8 @@ def run_cell(opts, root: Path) -> dict:
     }
     if opts.trace:
         result["metrics"], breakdown = per_layer(
-            bench, opts.workload, run, cut, peaks, device
+            bench, opts.workload, run, cut, peaks, device,
+            host_spans=getattr(module, "TRACE_HOST_SPANS", ()),
         )
         if breakdown:
             result["breakdown"] = breakdown
@@ -135,9 +136,10 @@ def run_cell(opts, root: Path) -> dict:
     return result
 
 
-def per_layer(bench, workload, run, cut, peaks, device):
+def per_layer(bench, workload, run, cut, peaks, device, host_spans=()):
     """The cell's per-layer metrics from its readers, and the trace's
-    breakdown; fills `device` with the slice's busy and window seconds."""
+    breakdown, its idle gaps labelled by the system's `host_spans`;
+    fills `device` with the slice's busy and window seconds."""
     import harness
     import tracing
     from harness import BenchError, say
@@ -148,7 +150,7 @@ def per_layer(bench, workload, run, cut, peaks, device):
     if path is None or cut.window_s is None:
         raise BenchError("the profiler wrote no trace")
     kernels = harness.load_json(BENCH / "kernels.json")
-    planes = tracing.read_planes(path)
+    planes = tracing.read_planes(path, host_spans)
     reduced = tracing.reduce(planes, cut.window_s, kernels)
     shutil.rmtree(cut.out_dir, ignore_errors=True)
     if not reduced or reduced["busy_s"] <= 0:

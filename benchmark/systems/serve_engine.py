@@ -6,7 +6,8 @@ Set-up starts the engine at the cell's configuration, lets its arena
 warm-up land, and sends two rounds of one device-only job per fixture
 family (drawn apart from the window's stream) so that the engine's
 union kernel bucket covers the whole mix, and is compiled, before the
-window. In the window each client submits the next contract of the
+window; then one walked job of each family the mix's `warm_walks`
+names, so that the host walk's first use is paid before the window. In the window each client submits the next contract of the
 stream and waits for it to settle; it submits no more once the window
 has closed. A job submitted inside the window is waited for after it,
 up to a minute, and counts with all of its wait.
@@ -20,21 +21,27 @@ and all of its time, and moves by less than one job's worth.
 from __future__ import annotations
 
 import bisect
+import itertools
+import json
 import shutil
 import statistics
 import threading
 import time
-from typing import Dict, List
+from typing import Dict, List, Optional, Tuple
 
 import generate
 from harness import BenchError, say
 
 #: how long a job submitted inside the window may take to settle
 SETTLE_GRACE_S = 60.0
-#: a walk that ran this share of its time limit may have been cut by
-#: it, or had its last solver queries shortened by it (a query gets at
-#: most what is left of the walk's limit)
+#: the share of its time limit that a walk the program says was cut
+#: must have run from `locked` to `done`: a real cut leaves less than
+#: the solver's margin of the limit (a query gets at most what is left
+#: of it, less 0.5 s), so an 8 s walk cut honestly runs over 6 s
 CUT_SHARE = 0.75
+#: the program's host spans that label the trace's idle gaps: a walk
+#: doing its own work, and a walk waiting to begin it
+TRACE_HOST_SPANS = ("service.host.walk", "service.host.lock_wait")
 
 
 class System:
@@ -87,6 +94,18 @@ class System:
                 thread.join(900)
             say(f"engine warm-up round {round_}: {len(jobs)} device-only "
                 f"jobs settled, kernel warm-ups joined")
+        # one walked job per family the mix names, drawn apart from the
+        # window's stream: the walk's first use lands here
+        for family in self.mix.get("warm_walks", ()):
+            walks = generate.stream(self.mix, self.seed, tag=f"warm-walk-{family}")
+            code = next((c for c, _, name in itertools.islice(walks, families)
+                         if name.startswith(family + "#")), None)
+            if code is None:
+                raise BenchError(f"no fixture family {family!r} to walk in set-up")
+            job = self.engine.submit(Job(code))
+            if self.engine.queue.wait_terminal(job.id, 900) is None:
+                raise BenchError("a walked warm-up job did not settle")
+            say(f"engine warm-up walk of a {family} mutant settled")
 
     def window(self, seconds: float) -> Dict:
         from mythril_tpu import observe
@@ -142,12 +161,10 @@ class System:
             ends = [d["settle_t"] for d in done if d["client"] == i]
             if ends and None not in ends:
                 spans.append(max(ends) - t_open)
-        cut = walks_cut([d["journey"] for d in done],
-                        [d["report"] for d in done])
         say(f"{len(done)} jobs submitted in the {window_s} s window, "
             f"{len(ok)} settled, {len(failed)} failed or unsettled; "
-            f"client busy spans {spans} s; "
-            f"{sum(cut)} walks cut")
+            f"client busy spans {spans} s")
+        cut = judge_walks(ok)
         return {
             "wall_s": window_s,
             "settled": len(ok),
@@ -162,7 +179,7 @@ class System:
                 {"code": bytes.fromhex(d["code"]),
                  "issues": (d["report"] or {}).get("issues") or [],
                  "family": d["family"], "walk_cut": c}
-                for c, d in zip(cut, done) if d["state"] == "done"
+                for c, d in zip(cut, ok)
             ],
         }
 
@@ -178,24 +195,62 @@ class System:
         self.engine.close()
 
 
-def walks_cut(journeys: List[List[Dict]], reports: List) -> List[bool]:
-    """Whether each job's host walk may have been cut by its time limit.
-    The engine runs its walks one at a time under the host symbolic lock,
-    so a walk began no earlier than its own `start` span and the `done`
-    of the walk before it; a walk whose time from there reaches CUT_SHARE
-    of its limit (the `timeout_s` of its start) is counted cut. A job
-    answered without a walk is not cut."""
-    walks = []
+def walks_cut(journeys: List[List[Dict]], reports: List) -> Tuple[List[bool], List[bool]]:
+    """Whether each job's host walk was cut by its time limit, by the
+    program's own word backed by the walk's own time; and whether the
+    program said `cut` of a walk too short for it. One flag per job in
+    each list.
+
+    A walked job counts as cut when its journey host-walk `done` says
+    `cut`, and its walk time from `locked` (the walk has begun its own
+    work) to `done` reaches CUT_SHARE of the limit on its `start`
+    (`timeout_s`). A real cut leaves less of the budget than the
+    solver's margin, so an honest one runs well past that share. A walk
+    that says `cut` in less time is not excused from its planted
+    weaknesses, and is flagged unbacked. The judgment holds whether
+    walks run one at a time or side by side. A walked job without one
+    start, locked and done, or whose done carries no `cut` or whose
+    start no limit, is an error. A job answered without a walk is not
+    cut."""
+    cut, unbacked = [], []
     for events, report in zip(journeys, reports):
-        rows = [r for r in events if r.get("tier") == "host-walk"]
-        if not rows and "host" not in (report or {}):
-            walks.append(None)
+        walk = _walk_rows(events, report, ("start", "locked", "done"))
+        if walk is None:
+            cut.append(False)
+            unbacked.append(False)
             continue
-        starts = [r for r in rows if r.get("event") == "start"]
-        dones = [r for r in rows if r.get("event") == "done"]
-        if len(starts) != 1 or len(dones) != 1:
-            raise BenchError("a walked job without one host-walk span")
-        walks.append((starts[0], dones[0]))
+        start, locked, done = walk
+        said = (done.get("attrs") or {}).get("cut")
+        limit = (start.get("attrs") or {}).get("timeout_s")
+        if said is None or limit is None:
+            raise BenchError("a host walk whose journey says no cut or no limit")
+        backed = done["t"] - locked["t"] >= CUT_SHARE * limit
+        cut.append(bool(said) and backed)
+        unbacked.append(bool(said) and not backed)
+    return cut, unbacked
+
+
+def _walk_rows(events: List[Dict], report, names) -> Optional[tuple]:
+    """The job's one host-walk row of each event in `names`, or None
+    for a job answered without a walk."""
+    rows = [r for r in events if r.get("tier") == "host-walk"]
+    if not rows and "host" not in (report or {}):
+        return None
+    found = []
+    for name in names:
+        hits = [r for r in rows if r.get("event") == name]
+        if len(hits) != 1:
+            raise BenchError(f"a walked job without one host-walk {name}")
+        found.append(hits[0])
+    return tuple(found)
+
+
+def _serial_cut(journeys: List[List[Dict]], reports: List) -> List[bool]:
+    """The judgment `walks_cut` replaced, kept only to print where the
+    two differ: walks taken to run one at a time, each begun at the
+    later of its own `start` and the `done` of the walk before it, and
+    cut when its time from there reaches CUT_SHARE of its limit."""
+    walks = [_walk_rows(e, r, ("start", "done")) for e, r in zip(journeys, reports)]
     ends = sorted(w[1]["t"] for w in walks if w is not None)
     out = []
     for w in walks:
@@ -208,3 +263,28 @@ def walks_cut(journeys: List[List[Dict]], reports: List) -> List[bool]:
         limit = (start.get("attrs") or {}).get("timeout_s")
         out.append(limit is None or end["t"] - began >= CUT_SHARE * limit)
     return out
+
+
+def judge_walks(jobs: List[Dict]) -> List[bool]:
+    """`walks_cut` over settled jobs ({family, journey, report}), with
+    what it found printed: the walks cut, those that said `cut` too
+    soon (`cuts_unbacked`) and those the serial judgment would have
+    judged otherwise (`cut_disagree`, not compared)."""
+    journeys = [j["journey"] for j in jobs]
+    reports = [j["report"] for j in jobs]
+    cut, unbacked = walks_cut(journeys, reports)
+    serial = _serial_cut(journeys, reports)
+
+    def walk_s(job):
+        t = {r.get("event"): r["t"] for r in job["journey"]
+             if r.get("tier") == "host-walk"}
+        return round(t["done"] - t["locked"], 3), round(t["done"] - t["start"], 3)
+
+    flagged = [j for j, u in zip(jobs, unbacked) if u]
+    differ = [(j, c) for j, c, s in zip(jobs, cut, serial) if c != s]
+    say(f"{sum(cut)} walks cut; cuts_unbacked {len(flagged)} "
+        + json.dumps([[j["family"], *walk_s(j)] for j in flagged]))
+    say(f"cut_disagree {len(differ)} " + json.dumps(
+        [[j["family"], "cut" if c else "not cut", *walk_s(j)] for j, c in differ]
+    ) + " (family, this judgment, walk s from locked, from start)")
+    return cut

@@ -1,12 +1,15 @@
 """A whole run of the serve cell with the look for a chip skipped, on the
 CPU at a small size, and the timed path broken underneath: an answer
 altered where it is produced, a walk that returns its state unchanged,
-a walk that drops one finding, or an answer that never comes, makes
-`correct` false. The same run unbroken is correct.
+a walk that drops one finding, a walk that says at once that its
+budget cut it, or an answer that never comes, makes `correct` false.
+The same run unbroken is correct.
 
 The mix is cut to the fixture families whose walks end well inside
 their limit on a CPU, so that every report is held to its planted
 weaknesses; the engine, its waves and its walks are the cell's own."""
+
+import re
 
 import pytest
 
@@ -105,27 +108,90 @@ def test_missing_answer_is_not_correct(serve, monkeypatch):
     assert result["correct"] is False
 
 
-def test_cut_walks_from_spans():
-    """Walks run one at a time: each began at the later of its own start
-    and the end of the walk before it."""
+def test_early_cut_is_not_excused(serve, monkeypatch, capfd):
+    """The walk returns at once, says it was cut by its budget and finds
+    nothing: a cut that short is not excused from the planted
+    weaknesses, and the run prints it as unbacked."""
+
+    def cut_at_once(payload):
+        return {"issues": [], "states": 0, "cut": "execution"}
+
+    monkeypatch.setattr(serve, "analyze_one_payload", cut_at_once)
+    result = _run(monkeypatch)
+    err = capfd.readouterr().err
+    assert result["compared"]["missed_planted"]["value"] >= 1
+    assert result["correct"] is False
+    said = re.search(r"bench: (\d+) walks cut; cuts_unbacked (\d+)", err)
+    assert said is not None
+    assert int(said.group(1)) == 0
+    assert int(said.group(2)) >= 1
+
+
+def _walk(start, locked, done, limit=8, **attrs):
+    """A walked job's host-walk rows; `locked` None leaves it out, and
+    attrs ride on `done`."""
+    rows = [{"t": start, "tier": "host-walk", "event": "start",
+             "attrs": {"timeout_s": limit}}]
+    if locked is not None:
+        rows.append({"t": locked, "tier": "host-walk", "event": "locked"})
+    rows.append({"t": done, "tier": "host-walk", "event": "done", "attrs": attrs})
+    return rows
+
+
+WALKED = {"host": {}}
+CUT_CASES = {
+    # one at a time, each begun when the one before it ended: the
+    # program and the serial judgment agree
+    "serial-agree": (
+        [_walk(0.0, 0.0, 2.0, cut=False),
+         _walk(0.5, 2.0, 10.0, cut=True),     # 8 s, its whole limit
+         _walk(1.0, 10.0, 15.0, cut=False),   # 5 s, after a 9 s wait
+         []],                                 # answered without a walk
+        [WALKED] * 3 + [{}],
+        [False, True, False, False], [False] * 4, [False, True, False, False],
+    ),
+    # four side by side: the cut one ran 8.4 s from locked, but another
+    # ended 0.6 s before it, so the serial judgment misses the cut
+    "side-by-side": (
+        [_walk(0.0, 0.0, 3.0, cut=False),
+         _walk(0.2, 0.2, 8.6, cut=True),
+         _walk(0.4, 0.4, 5.0, cut=False),
+         _walk(0.6, 0.6, 8.0, cut=False)],
+        [WALKED] * 4,
+        [False, True, False, False], [False] * 4, [False] * 4,
+    ),
+    # says cut after 1 s: held to its planted weaknesses, and unbacked
+    "cut-too-soon": (
+        [_walk(0.0, 0.0, 1.0, cut="execution")],
+        [WALKED],
+        [False], [True], [False],
+    ),
+    # ran to its end in 7.3 s of its 8: held to its planted weaknesses,
+    # where the serial judgment excused it for passing the share
+    "ended-late": (
+        [_walk(0.0, 0.0, 7.3, cut=False)],
+        [WALKED],
+        [False], [False], [True],
+    ),
+    "no-walk": ([[]], [{}], [False], [False], [False]),
+    "done-without-cut": ([_walk(0.0, 0.0, 8.0)], [WALKED], None, None, None),
+    "no-locked": ([_walk(0.0, None, 8.0, cut=True)], [WALKED], None, None, None),
+    "no-walk-span": ([[]], [WALKED], None, None, None),
+}
+
+
+@pytest.mark.parametrize("case", list(CUT_CASES))
+def test_cut_walks_from_spans(case):
+    """A walk is cut by the program's `cut` on its journey `done`,
+    backed by its own time from `locked`; the serial judgment it
+    replaced is kept only to print where the two differ."""
     serve_engine = harness.load_module(
         harness.BENCH / "systems" / "serve_engine.py", "t_serve_engine"
     )
-
-    def walk(start, done, limit=8):
-        return [
-            {"t": start, "tier": "host-walk", "event": "start",
-             "attrs": {"timeout_s": limit}},
-            {"t": done, "tier": "host-walk", "event": "done"},
-        ]
-
-    journeys = [
-        walk(0.0, 2.0),    # 2 s alone
-        walk(0.5, 10.0),   # began at 2.0: 8 s, its whole limit
-        walk(1.0, 15.0),   # began at 10.0: 5 s, waited 9 s before
-        [],                # answered without a walk
-    ]
-    reports = [{"host": {}}] * 3 + [{}]
-    assert serve_engine.walks_cut(journeys, reports) == [False, True, False, False]
-    with pytest.raises(harness.BenchError):
-        serve_engine.walks_cut([[]], [{"host": {}}])
+    journeys, reports, cut, unbacked, serial = CUT_CASES[case]
+    if cut is None:
+        with pytest.raises(harness.BenchError):
+            serve_engine.walks_cut(journeys, reports)
+        return
+    assert serve_engine.walks_cut(journeys, reports) == (cut, unbacked)
+    assert serve_engine._serial_cut(journeys, reports) == serial

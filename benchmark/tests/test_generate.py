@@ -51,3 +51,16 @@ def test_stream_is_unique_and_round_robin():
     assert len(set(families)) == 13
     again = generate.stream(FRESH, BIG)
     assert [next(again) for _ in range(40)] == rows
+
+
+def test_fixture_round_follows_the_mix_order():
+    order = FRESH["parts"][0]["order"]
+    it = generate.stream(FRESH, BIG)
+    rows = [next(it) for _ in range(26)]
+    families = [r[2].split("#")[0] for r in rows]
+    assert families == order + order
+    # families the order leaves out follow it, by name
+    mix = {"parts": [{"shape": "fixture_mutant", "order": ["suicide.sol"]}]}
+    it = generate.stream(mix, BIG)
+    names = [next(it)[2].split("#")[0] for _ in range(3)]
+    assert names == ["suicide.sol", "calls.sol", "environments.sol"]

@@ -1,6 +1,7 @@
 """A short profiler slice of the measured window, and its reduction to
 device busy time, kernel time by executable name, the top device
-operations and the longest idle gaps.
+operations and the longest idle gaps, each labelled by what the
+program's host spans were doing across it.
 
 The slice runs on a timer thread beside the window: it starts `start_s`
 after the window opens and lasts `length_s`. The reduction reads the
@@ -106,25 +107,60 @@ def device_lines(planes) -> List[Dict]:
     return out
 
 
-def read_planes(path: Path) -> List[Dict]:
+def read_planes(path: Path, host_spans: Iterable[str] = ()) -> List[Dict]:
     """The trace's planes as plain data: {name, lines: [{name, events:
-    [(event name, start s, end s)]}]}; host planes keep no events."""
+    [(event name, start s, end s)]}]}. Device planes keep every event;
+    host planes keep only the program's spans named in `host_spans`."""
     from jax.profiler import ProfileData
 
+    keep = frozenset(host_spans)
     data = ProfileData.from_file(str(path))
     planes = []
     for plane in data.planes:
+        device = plane.name.startswith("/device:")
         lines = []
         for line in plane.lines:
             events = []
-            if plane.name.startswith("/device:"):
-                events = [
-                    (e.name, e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9)
-                    for e in line.events
-                ]
+            for e in line.events:
+                name = e.name if device else _span_name(e.name)
+                if device or name in keep:
+                    events.append((name, e.start_ns * 1e-9,
+                                   (e.start_ns + e.duration_ns) * 1e-9))
             lines.append({"name": line.name, "events": events})
         planes.append({"name": plane.name, "lines": lines})
     return planes
+
+
+def _span_name(name: str) -> str:
+    """A host event's name without the `#key=value,...#` a TraceMe may
+    carry its arguments in."""
+    return name.split("#", 1)[0]
+
+
+def host_spans(planes) -> List[Tuple[str, float, float]]:
+    """Every event kept on the host planes, as (name, start_s, end_s)."""
+    return [
+        event
+        for plane in planes if not plane["name"].startswith("/device:")
+        for line in plane["lines"]
+        for event in line["events"]
+    ]
+
+
+def _host_label(spans, start: float, end: float) -> str:
+    """The host spans open at some point of [start, end], by name: how
+    many, and the seconds of the stretch that they cover together, as
+    "service.host.lock_wait x2 4.20s + service.host.walk x1 6.00s"."""
+    inside: Dict[str, List[Tuple[float, float]]] = {}
+    for name, s, e in spans:
+        if s < end and e > start:
+            inside.setdefault(name, []).append((max(s, start), min(e, end)))
+    if not inside:
+        return "no host span"
+    return " + ".join(
+        f"{name} x{len(cut)} {sum(e - s for s, e in _union(cut)):.2f}s"
+        for name, cut in sorted(inside.items())
+    )
 
 
 def reduce(planes: List[Dict], window_s: float, kernels: Dict[str, List[str]]) -> Dict:
@@ -132,7 +168,8 @@ def reduce(planes: List[Dict], window_s: float, kernels: Dict[str, List[str]]) -
     device planes), per-kernel device seconds and event counts
     (executables whose name contains one of the kernel's patterns), the
     ten device operations that took most time and the ten longest idle
-    gaps, each labelled by the executable that ran before it."""
+    gaps, each labelled by the host spans open across it and by the
+    executable that ran before it."""
     devs = device_lines(planes)
     if not devs:
         return {}
@@ -141,7 +178,7 @@ def reduce(planes: List[Dict], window_s: float, kernels: Dict[str, List[str]]) -
     kernel_n = {k: 0 for k in kernels}
     op_time: Dict[str, float] = {}
     module_time: Dict[str, float] = {}
-    gaps: List[Tuple[str, float]] = []
+    gaps: List[Tuple[str, float, float]] = []
     for dev in devs:
         events = dev["ops"] or dev["modules"]
         union = _union((s, e) for _n, s, e in events)
@@ -164,11 +201,12 @@ def reduce(planes: List[Dict], window_s: float, kernels: Dict[str, List[str]]) -
                 continue
             i = bisect.bisect_right(starts, e0) - 1
             label = f"after {modules[i][0]}" if i >= 0 else "before first executable"
-            gaps.append((label, s1 - e0))
+            gaps.append((label, e0, s1))
     n = len(devs)
     busy_s = min(busy_total / n, window_s)
     top_ops = sorted(op_time.items(), key=lambda kv: -kv[1])[:10]
-    top_gaps = sorted(gaps, key=lambda kv: -kv[1])[:10]
+    top_gaps = sorted(gaps, key=lambda g: g[1] - g[2])[:10]
+    spans = host_spans(planes)
     return {
         "chips": n,
         "busy_s": busy_s,
@@ -180,5 +218,8 @@ def reduce(planes: List[Dict], window_s: float, kernels: Dict[str, List[str]]) -
             [name, secs / n]
             for name, secs in sorted(module_time.items(), key=lambda kv: -kv[1])[:10]
         ],
-        "idle_gaps": [[name, secs] for name, secs in top_gaps],
+        "idle_gaps": [
+            [f"{_host_label(spans, e0, s1)} | {label}", s1 - e0]
+            for label, e0, s1 in top_gaps
+        ],
     }
