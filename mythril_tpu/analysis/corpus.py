@@ -20,9 +20,11 @@ SURVEY.md §2.4 maps that loop onto two axes here:
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import multiprocessing as mp
 import os
+import threading
 import time
 import traceback
 from typing import Dict, List, Optional, Tuple
@@ -1255,11 +1257,32 @@ def _analyze_one(payload: Tuple) -> Dict:
             args.deterministic_solving = restore_deterministic
 
 
-#: public name for the pooled-mode worker: the analysis service
-#: (mythril_tpu/service/engine.py) feeds finished device stripes
-#: through the exact per-contract pipeline the corpus pool runs, so
-#: the payload contract is shared, not duplicated
-analyze_one_payload = _analyze_one
+#: the walker process (service/walkers.py) that host walks on the
+#: calling thread go to; unset, they run in this process
+_BOUND = threading.local()
+
+
+@contextlib.contextmanager
+def walker_bound(walker):
+    """Send this thread's `analyze_one_payload` calls to `walker` (a
+    `service.walkers.Walker`; None keeps them in this process)."""
+    _BOUND.walker = walker
+    try:
+        yield
+    finally:
+        _BOUND.walker = None
+
+
+def analyze_one_payload(payload: Tuple) -> Dict:
+    """The per-contract pipeline the corpus pool runs, for the analysis
+    service (mythril_tpu/service/engine.py): it feeds finished device
+    stripes through it, so the payload contract is shared, not
+    duplicated. The walk runs in the walker bound to the calling
+    thread (`walker_bound`), or else in this process."""
+    walker = getattr(_BOUND, "walker", None)
+    if walker is None:
+        return _analyze_one(payload)
+    return walker.walk(payload)
 
 
 def analyze_corpus(

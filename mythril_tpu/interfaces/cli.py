@@ -888,7 +888,10 @@ def build_parser() -> ArgumentParser:
         "--host-workers",
         type=int,
         default=1,
-        help="host-analysis worker threads consuming finished stripes",
+        help="host-analysis workers consuming finished stripes; with two "
+        "or more (and a CPU left for the engine per extra worker) the "
+        "walks run side by side in walker processes, otherwise one at a "
+        "time in this process",
     )
     serve.add_argument(
         "--no-host-walk",

@@ -367,6 +367,68 @@ class MetricsRegistry:
                         out.setdefault(name, {})[key] = delta
         return out
 
+    def growth(self, marker: Dict) -> Dict[str, Dict]:
+        """Counter and histogram growth since `marker`, each family with
+        its kind, help and bucket bounds: the form `fold` adds into
+        another registry (a walker process hands a walk's growth back
+        to the serve engine's). A histogram grows by its bucket counts,
+        sum and count. Gauges and collector samples have no growth and
+        are left out."""
+        now = self.snapshot()
+        with self._lock:
+            families = {
+                name: (m.kind, m.help, m.buckets)
+                for name, m in self._metrics.items()
+                if m.kind != GAUGE
+            }
+        out: Dict[str, Dict] = {}
+        for name, (kind, help_text, buckets) in families.items():
+            base = marker.get(name, {})
+            series = {}
+            for key, value in now.get(name, {}).items():
+                if kind == HISTOGRAM:
+                    prev = base.get(key)
+                    counts = list(value["buckets"])
+                    total, n = value["sum"], value["count"]
+                    if prev is not None:
+                        counts = [a - b for a, b in zip(counts, prev["buckets"])]
+                        total -= prev["sum"]
+                        n -= prev["count"]
+                    if n or total:
+                        series[key] = (counts, total, n)
+                else:
+                    grown = value - base.get(key, 0.0)
+                    if grown:
+                        series[key] = grown
+            if series:
+                out[name] = {
+                    "kind": kind, "help": help_text, "buckets": buckets,
+                    "series": series,
+                }
+        return out
+
+    def fold(self, growth: Dict[str, Dict]) -> None:
+        """Add another registry's `growth` into this one's series. A
+        histogram whose bounds differ here takes the grown count in the
+        bucket of its mean, as `add_raw` does."""
+        for name, family in growth.items():
+            metric = self._metric(
+                name, family["kind"], family["help"], family["buckets"]
+            )
+            for key, value in family["series"].items():
+                if metric.kind != HISTOGRAM:
+                    metric._inc(key, value)
+                    continue
+                counts, total, n = value
+                if tuple(family["buckets"]) != metric.buckets:
+                    metric._add_raw(key, total, n)
+                    continue
+                with self._lock:
+                    row = metric._hist_row(key)
+                    row[0] = [a + b for a, b in zip(row[0], counts)]
+                    row[1] += total
+                    row[2] += n
+
     # -- exposition ----------------------------------------------------
     def prometheus_text(self) -> str:
         """The whole registry in the Prometheus text exposition format

@@ -99,17 +99,24 @@ def marker() -> Dict:
     return registry().marker()
 
 
-def attribution(since: Optional[Dict] = None) -> Dict[str, Dict]:
+def attribution(
+    since: Optional[Dict] = None, growth: Optional[Dict] = None
+) -> Dict[str, Dict]:
     """The per-origin attribution table:
 
         {origin: {"queries": n, "verdicts": {verdict: n},
                   "wall_s": seconds, "escalations": n}}
 
     Over the whole process, or as a delta when `since` (a `marker()`)
-    is given — the per-run form bench.py and the report meta embed."""
-    _metrics()
-    reg = registry()
-    snap = reg.since(since) if since is not None else reg.snapshot()
+    is given — the per-run form bench.py and the report meta embed —
+    or of a `growth` another process's registry handed back (one walk
+    in a serve walker process)."""
+    if growth is not None:
+        snap = {name: family["series"] for name, family in growth.items()}
+    else:
+        _metrics()
+        reg = registry()
+        snap = reg.since(since) if since is not None else reg.snapshot()
     out: Dict[str, Dict] = {}
 
     def row(origin: str) -> Dict:

@@ -22,8 +22,9 @@ This engine owns the device for its lifetime and amortizes all three:
 - **Host pool** — finished device phases hand off to a host worker
   (analysis/corpus.py pooled mode, outcome injected) so device waves
   and host `fire_lasers` overlap continuously. Host symbolic state is
-  process-global, so in-process workers serialize on
-  HOST_SYMBOLIC_LOCK.
+  process-global, so with the CPUs for it the walks run side by side
+  in walker processes (service/walkers.py); otherwise in-process
+  workers serialize on HOST_SYMBOLIC_LOCK.
 - **Drain** — `drain()` (wired to SIGTERM by the server) finishes the
   in-flight wave, then checkpoints every unfinished job's seeded
   frontier to a replayable npz (laser/batch/checkpoint.py, shape
@@ -51,6 +52,7 @@ from mythril_tpu.observe.registry import _label_key
 from mythril_tpu.observe.spans import flight_recorder, trace
 from mythril_tpu.service.jobs import Job, JobQueue, JobState
 from mythril_tpu.service.lane_allocator import LaneAllocator
+from mythril_tpu.service.walkers import WalkerPool, walker_width
 
 log = logging.getLogger(__name__)
 
@@ -656,6 +658,10 @@ class AnalysisEngine:
             thread_name_prefix="myth-serve-host",
         )
         self._host_inflight: Dict[str, Tuple] = {}
+        #: walker processes the host walks run in side by side, started
+        #: with the engine when `host_workers` and the CPUs allow two or
+        #: more (None walks in this process, one at a time)
+        self._walkers: Optional[WalkerPool] = None
         # the learned tier-ladder router (mythril_tpu/routing): priced
         # admission + in-flight promotion. None (no artifact, refused
         # artifact, or router=False) keeps today's ladder bit-for-bit.
@@ -697,6 +703,10 @@ class AnalysisEngine:
         self._c_host_completed = reg.counter(
             "mtpu_service_host_completed_total", "host walks finished"
         ).labels(**lab)
+        self._c_host_walks = reg.counter(
+            "mtpu_serve_host_walks_total",
+            "host walks by where they ran: a walker process or in-process",
+        )
         self._c_rebuckets = reg.counter(
             "mtpu_service_kernel_rebuckets_total",
             "code-capacity re-buckets (arena recompiles)",
@@ -1063,18 +1073,34 @@ class AnalysisEngine:
                 jax.block_until_ready(steps)
         except Exception:
             log.warning("arena warmup failed", exc_info=True)
+
+    def _warm_up(self, arena: bool) -> None:
+        """Readiness: the arena warm-up wave (when `arena`), and every
+        walker's warm-up walk, which runs in its own process alongside
+        the arena's compile or cache loads."""
+        try:
+            if arena:
+                self._arena_warmup()
+            if self._walkers is not None:
+                self._walkers.wait_warm()
         finally:
             self._warm_done.set()
 
     def start(self) -> "AnalysisEngine":
         if self._thread is None:
+            arena = self.cfg.arena_warmup and not self._warm_done.is_set()
+            width = walker_width(self.cfg.host_workers)
+            if width > 1:
+                self._walkers = WalkerPool(width)
+                self._warm_done.clear()
             self._thread = threading.Thread(
                 target=self._loop, name="myth-serve-waves", daemon=True
             )
             self._thread.start()
-            if self.cfg.arena_warmup and not self._warm_done.is_set():
+            if not self._warm_done.is_set():
                 threading.Thread(
-                    target=self._arena_warmup,
+                    target=self._warm_up,
+                    args=(arena,),
                     name="myth-arena-warmup",
                     daemon=True,
                 ).start()
@@ -1608,6 +1634,9 @@ class AnalysisEngine:
                     job.degraded.append("interrupted")
                     self._finalize(job, track, outcome, host_result=None)
         self._host_inflight.clear()
+        # the walks are over: the walker processes exit with the pool
+        if self._walkers is not None:
+            self._walkers.close()
         # in-flight kernel warmups: an XLA compile racing interpreter
         # teardown aborts the process (std::terminate), so the drain
         # waits them out (bounded — a compile is seconds, and no new
@@ -2569,7 +2598,7 @@ class AnalysisEngine:
         self._host_inflight[job.id] = (future, track, outcome)
 
     def _host_task(self, job: Job, track: _JobTrack, outcome: Dict) -> None:
-        from mythril_tpu.analysis.corpus import analyze_one_payload
+        from mythril_tpu.analysis import corpus
         from mythril_tpu.support.host_lock import HOST_SYMBOLIC_LOCK
 
         timeout = self.cfg.execution_timeout
@@ -2603,41 +2632,65 @@ class AnalysisEngine:
             timeout_s=timeout,
         )
         # host symbolic state (term arena, CDCL session) is
-        # process-global: in-process workers serialize here. The wait
-        # is a span of its own and `locked` ends it on the journey;
-        # `done` is recorded before the release, so a walk's locked to
-        # done holds its own work and nothing else
+        # process-global: a walk waits for a free walker process, or
+        # in-process walks serialize on the lock. The wait is a span of
+        # its own and `locked` ends it on the journey; `done` is
+        # recorded before the release, so a walk's locked to done holds
+        # its own work and nothing else
+        walkers = self._walkers
+        walker = None
         with trace("service.host.lock_wait", track="service", job=job.id):
-            HOST_SYMBOLIC_LOCK.acquire()
+            if walkers is None:
+                HOST_SYMBOLIC_LOCK.acquire()
+            else:
+                walker = walkers.acquire()
         try:
             observe.journey_event(
-                job.journey_id, journey.TIER_HOST_WALK, "locked"
+                job.journey_id, journey.TIER_HOST_WALK, "locked",
+                **({} if walker is None else {"walker": walker.index}),
             )
-            solver_before = observe.solver_marker()
+            solver_before = observe.solver_marker() if walker is None else None
             try:
                 with trace(
                     "service.host.walk", track="service", job=job.id
-                ):
-                    result = analyze_one_payload(payload)
+                ), corpus.walker_bound(walker):
+                    result = corpus.analyze_one_payload(payload)
             except CancelledError:
                 raise
             except Exception as why:  # analyze_one_payload already catches;
                 result = {"issues": [], "states": 0, "error": str(why)}
             self._walk_done(job, result, solver_before)
         finally:
-            HOST_SYMBOLIC_LOCK.release()
+            if walker is None:
+                HOST_SYMBOLIC_LOCK.release()
+            else:
+                walkers.release(walker)
+        self._c_host_walks.labels(
+            engine=self._eid,
+            walker="inproc" if walker is None else "process",
+        ).inc()
         self._host_inflight.pop(job.id, None)
         self._c_host_completed.inc()
         self._finalize(job, track, outcome, host_result=result)
 
     def _walk_done(self, job: Job, result: Dict, solver_before) -> None:
-        """The walk's journey events, under HOST_SYMBOLIC_LOCK: the
-        solver attribution delta and the phase split are this walk's
-        alone (walks hold the lock; device-only waves run no solver or
-        LASER step). The ladder hops (device-first vs CDCL) land as one
-        solver-tier event; `done` carries the budget cut and the split."""
+        """The walk's journey events. The solver attribution and the
+        phase split are this walk's alone: a walker process hands back
+        its walk's registry growth (`telemetry`), folded here into this
+        process's registry; an in-process walk holds
+        HOST_SYMBOLIC_LOCK, and its delta since `solver_before` is its
+        own (device-only waves run no solver or LASER step). The ladder
+        hops (device-first vs CDCL) land as one solver-tier event;
+        `done` carries the budget cut, the split and the walking pid."""
+        grown = result.pop("telemetry", None)
+        if grown is not None:
+            observe.registry().fold(grown)
         try:
-            attribution = observe.solver_attribution(solver_before)
+            attribution = (
+                observe.solver_attribution(solver_before)
+                if grown is None
+                else observe.solver_attribution(growth=grown)
+            )
             if attribution:
                 observe.journey_event(
                     job.journey_id, journey.TIER_SOLVER, "escalations",
@@ -2664,6 +2717,7 @@ class AnalysisEngine:
             solve_s=phase("solve"),
             solve_n=phase("solve", "count"),
             concretize_s=phase("concretize"),
+            pid=result.get("pid", os.getpid()),
         )
 
     def _finalize(
@@ -3143,6 +3197,10 @@ class AnalysisEngine:
             "solver": self._solver_stats(snap),
             "host_pool": {
                 "workers": max(1, self.cfg.host_workers),
+                # walker processes the walks run in (0: in-process)
+                "walkers": (
+                    len(self._walkers.walkers) if self._walkers else 0
+                ),
                 "inflight": len(self._host_inflight),
                 "completed": int(sv("mtpu_service_host_completed_total")),
             },

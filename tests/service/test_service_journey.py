@@ -17,10 +17,13 @@ real engine. CPU-only."""
 from __future__ import annotations
 
 import json
+import os
+import threading
 
 import pytest
 
 from mythril_tpu import observe
+from mythril_tpu.analysis import corpus
 from mythril_tpu.analysis.corpusgen import clean_contract
 from mythril_tpu.analysis.static import analysis_config_fingerprint
 from mythril_tpu.observe.registry import registry
@@ -244,7 +247,23 @@ def solve_count() -> int:
     ).count
 
 
-def test_host_walk_journey_lock_wait_walk_and_split():
+@pytest.mark.parametrize("cpus", [1, 8], ids=["in-process", "walkers"])
+def test_host_walk_journey_lock_wait_walk_and_split(cpus, monkeypatch):
+    """Two host workers: with one CPU the walks take turns on the host
+    symbolic lock in this process; with CPUs to spare each runs in a
+    walker process of its own, side by side."""
+    monkeypatch.setattr(corpus, "_effective_cpus", lambda: cpus)
+    if cpus > 1:
+        # each walk waits inside its walk span until the other has begun
+        # its own: walks that took turns would break the barrier
+        barrier = threading.Barrier(2, timeout=60.0)
+        walk = corpus.analyze_one_payload
+
+        def side_by_side(payload):
+            barrier.wait()
+            return walk(payload)
+
+        monkeypatch.setattr(corpus, "analyze_one_payload", side_by_side)
     engine = AnalysisEngine(ServiceConfig(**WALK_CFG)).start()
     try:
         jobs = [engine.submit(Job(code)) for code in (KILLABLE, writer(2))]
@@ -264,10 +283,21 @@ def test_host_walk_journey_lock_wait_walk_and_split():
             assert "cut_budget" not in attrs
             assert attrs["solve_s"] >= 0.0 and attrs["solve_n"] >= 1
             assert {"step_s", "feasibility_s", "concretize_s"} <= set(attrs)
-        # the lock serializes walks: the later one is granted it only
-        # once the earlier one is done
         first, second = sorted(walks, key=lambda w: w["locked"]["t"])
-        assert second["locked"]["t"] >= first["done"]["t"]
+        pids = {w["done"]["attrs"]["pid"] for w in walks}
+        if cpus == 1:
+            # the lock serializes walks: the later one is granted it only
+            # once the earlier one is done
+            assert second["locked"]["t"] >= first["done"]["t"]
+            assert pids == {os.getpid()}
+            assert engine.stats()["host_pool"]["walkers"] == 0
+        else:
+            # side by side: the later walk begins before the earlier one
+            # is done, in another walker process than it
+            assert second["locked"]["t"] < first["done"]["t"]
+            assert len(pids) == 2 and os.getpid() not in pids
+            assert {w["locked"]["attrs"]["walker"] for w in walks} == {0, 1}
+            assert engine.stats()["host_pool"]["walkers"] == 2
         # both spans are on the flight recorder for each job
         for job in jobs:
             names = {
